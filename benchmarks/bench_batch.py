@@ -688,8 +688,9 @@ def main() -> None:
         ap.error("--lattice shards one query's lane space over a mesh; "
                  "pass --devices N with N >= 2")
     # must land before the first jax import: backends read XLA_FLAGS once
-    from repro.hostdev import ensure_host_devices
+    from repro.hostdev import ensure_compile_cache, ensure_host_devices
     ensure_host_devices(args.devices)
+    ensure_compile_cache()
     nq, repeat = args.queries, args.repeat
     if args.smoke:
         # min-of-2: a single repeat makes the regression gate hostage to

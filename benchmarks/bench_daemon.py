@@ -32,6 +32,13 @@ against one shared ``PlanCache``, same request order) and costs must match
 **bit-identically** — the daemon may never change results, only reuse
 warm state.
 
+One process per device: while the daemon is alive it is the only process
+that starts a JAX backend.  The client subprocess runs with
+``JAX_PLATFORMS=cpu`` (it needs sockets, graph builders and the host
+plan re-coster only), this process starts no backend until the daemon has
+exited (gated as ``parent_backend_free``), and the in-process reference
+replay runs after the drain.
+
     PYTHONPATH=src python benchmarks/bench_daemon.py --json BENCH_daemon.json
     PYTHONPATH=src python benchmarks/bench_daemon.py --smoke   # CI-sized
     python benchmarks/check_regression.py BENCH_daemon.json \
@@ -63,6 +70,15 @@ def _percentiles(xs, ps=(50, 95, 99)) -> dict:
 
 def _costs(results) -> list[float]:
     return [float(r.cost) for r in results]
+
+
+def _backend_started() -> bool:
+    """Whether this process has started a JAX backend (importing jax does
+    not start one; building device arrays or listing devices does)."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()
 
 
 def _spawn_daemon(sockp: str, ckpt: str, queue_depth: int,
@@ -137,7 +153,6 @@ def bench(nq: int = 32, seed: int = 0, devices: int | None = None,
           load_arrivals: int = 60, smoke: bool = False) -> dict:
     if smoke:
         nq, load_tenants, load_arrivals = 8, 2, 12
-    from repro.core.engine import optimize_many
     from repro.core.plancache import PlanCache
     from repro.daemon import DaemonClient
     from repro.workloads.generators import mixed_stream
@@ -169,6 +184,7 @@ def bench(nq: int = 32, seed: int = 0, devices: int | None = None,
         env = dict(os.environ)
         env["PYTHONPATH"] = os.path.join(REPO, "src") \
             + os.pathsep + env.get("PYTHONPATH", "")
+        env["JAX_PLATFORMS"] = "cpu"           # the daemon owns the device
         out = subprocess.run(
             [sys.executable, "-m", "repro.daemon.client", "--socket", sockp,
              "--queries", str(nq), "--seed", str(seed), "--tenant", "proc2",
@@ -196,17 +212,6 @@ def bench(nq: int = 32, seed: int = 0, devices: int | None = None,
             exec_after["compiles"] - exec_before["compiles"]
         rep["fresh_retrace_delta"] = \
             exec_after["retraces"] - exec_before["retraces"]
-        # ---- in-process reference: same request order, one shared cache ---
-        ref_cache = PlanCache()
-        kw = {"devices": devices} if devices else {}
-        ref_cold = optimize_many(graphs, cache=ref_cache, **kw)
-        ref_warm = optimize_many(graphs, cache=ref_cache, **kw)
-        ref_p2 = optimize_many(graphs, cache=ref_cache, **kw)
-        ref_fresh = optimize_many(fresh_graphs, cache=ref_cache, **kw)
-        rep["costs_equal_cold"] = _costs(cold) == _costs(ref_cold)
-        rep["costs_equal_warm"] = _costs(warm) == _costs(ref_warm)
-        rep["costs_equal_proc2"] = p2_round["costs"] == _costs(ref_p2)
-        rep["costs_equal_fresh"] = _costs(fresh) == _costs(ref_fresh)
         # ---- phase 5: open-loop Poisson load (reported, never gated) ------
         rep["load"] = _load_phase(sockp, graphs[:2], load_tenants,
                                   load_rate_hz, load_arrivals, seed)
@@ -217,6 +222,7 @@ def bench(nq: int = 32, seed: int = 0, devices: int | None = None,
                                ("requests", "queries", "shed", "errors",
                                 "flights", "exec", "plancache")}
         c.close()
+        rep["parent_backend_free"] = not _backend_started()
         # ---- phase 6: SIGTERM drain ---------------------------------------
         proc.send_signal(signal.SIGTERM)
         rc = proc.wait(timeout=60)
@@ -231,6 +237,19 @@ def bench(nq: int = 32, seed: int = 0, devices: int | None = None,
         for p in (ckpt, sockp):
             if os.path.exists(p):
                 os.unlink(p)
+    # ---- in-process reference, now that the daemon released the device:
+    # same request order, one shared cache
+    from repro.core.engine import optimize_many
+    ref_cache = PlanCache()
+    kw = {"devices": devices} if devices else {}
+    ref_cold = optimize_many(graphs, cache=ref_cache, **kw)
+    ref_warm = optimize_many(graphs, cache=ref_cache, **kw)
+    ref_p2 = optimize_many(graphs, cache=ref_cache, **kw)
+    ref_fresh = optimize_many(fresh_graphs, cache=ref_cache, **kw)
+    rep["costs_equal_cold"] = _costs(cold) == _costs(ref_cold)
+    rep["costs_equal_warm"] = _costs(warm) == _costs(ref_warm)
+    rep["costs_equal_proc2"] = p2_round["costs"] == _costs(ref_p2)
+    rep["costs_equal_fresh"] = _costs(fresh) == _costs(ref_fresh)
     return {"queries": nq, "seed": seed, "daemon": rep}
 
 
@@ -397,6 +416,8 @@ def main() -> int:
     ap.add_argument("--json", type=str, default=None,
                     help="write the report here ('-' for stdout)")
     args = ap.parse_args()
+    from repro.hostdev import ensure_compile_cache
+    ensure_compile_cache()     # the daemon child and the reference replay
     if args.chaos:
         rep = bench_chaos(seed=args.seed, smoke=args.smoke)
         ch = rep["chaos"]
@@ -442,6 +463,8 @@ def main() -> int:
           f"p99 {ld['latency_s']['p99']*1e3:.1f}ms")
     print(f"[daemon] drain: exit {d['drain_exit_code']} checkpoint "
           f"{d['checkpoint_entries']} entries clean {d['drain_clean']}")
+    print(f"[daemon] parent started no JAX backend while the daemon ran: "
+          f"{d['parent_backend_free']}")
     if args.json:
         payload = json.dumps(rep, indent=2, sort_keys=True)
         if args.json == "-":
@@ -453,7 +476,8 @@ def main() -> int:
           and d["costs_equal_proc2"] and d["costs_equal_fresh"]
           and d["warm_compile_delta"] == 0 and d["proc2_compile_delta"] == 0
           and d["fresh_retrace_delta"] == 0
-          and d["proc2_cache_hits"] >= 1 and d["drain_clean"])
+          and d["proc2_cache_hits"] >= 1 and d["drain_clean"]
+          and d["parent_backend_free"])
     return 0 if ok else 1
 
 

@@ -208,8 +208,9 @@ def check_daemon(current: dict, baseline: dict) -> list[str]:
     costs bit-identical to the in-process replay, zero executable compiles
     on the warm / second-process / fresh phases beyond the committed
     baseline deltas, at least one cross-client plan-cache hit from the
-    second client process, and a clean SIGTERM drain (exit 0 + loadable
-    checkpoint).  Latency percentiles and shed counts under the open-loop
+    second client process, a clean SIGTERM drain (exit 0 + loadable
+    checkpoint), and no JAX backend in the benchmark process while the
+    daemon holds the device.  Latency percentiles and shed counts under the open-loop
     Poisson load are reported, never gated."""
     base_d = baseline.get("daemon")
     cur_d = current.get("daemon")
@@ -268,6 +269,11 @@ def check_daemon(current: dict, baseline: dict) -> list[str]:
             f"{cur_d.get('drain_exit_code')} / checkpoint "
             f"{cur_d.get('checkpoint_entries')} entries (SIGTERM must "
             "drain, checkpoint atomically, and exit 0)")
+    if not cur_d.get("parent_backend_free", False):
+        errors.append(
+            "[daemon:device] the benchmark process started a JAX backend "
+            "while the daemon was alive (one process per device: only the "
+            "daemon may hold it)")
     return errors
 
 

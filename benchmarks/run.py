@@ -12,6 +12,8 @@ import time
 
 
 def main() -> None:
+    from repro.hostdev import ensure_compile_cache
+    ensure_compile_cache()                  # before the first jax import
     from . import paper_figs as pf
     wanted = [a for a in sys.argv[1:] if not a.startswith("-")]
     t0 = time.time()

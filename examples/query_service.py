@@ -109,8 +109,9 @@ def main():
                          "UnionDP-tier query")
     args = ap.parse_args()
     # before the first jax import: backends read XLA_FLAGS exactly once
-    from repro.hostdev import ensure_host_devices
+    from repro.hostdev import ensure_compile_cache, ensure_host_devices
     ensure_host_devices(args.devices)
+    ensure_compile_cache()
 
     from repro.core.plan import validate_plan
     from repro.execution import executor as ex

@@ -1150,11 +1150,10 @@ def optimize_many(graphs: list[JoinGraph], algorithm=UNSET, chunk=UNSET,
                 try:
                     rs = eng.run()
                     redispatched = False
-                except Exception:
-                    # device-execution failure on the mesh: re-dispatch the
-                    # bucket on the in-process single-device engine (the
-                    # degenerate 1-device case is proven bit-identical by
-                    # tests/test_shard.py)
+                except _shard.REDISPATCH_ERRORS as e:
+                    # device-runtime failure on the mesh: re-dispatch the
+                    # bucket on the in-process single-device engine
+                    _shard.log_redispatch(e, len(group))
                     eng = BatchEngine([graphs[qi] for qi in group],
                                       chunk=run_chunk, algorithm=run_space,
                                       pipeline=pipeline, deadline_s=_left(),

@@ -48,7 +48,8 @@ INF = np.float32(np.inf)
 
 def _use_pallas() -> bool:
     """REPRO_PALLAS=1 routes the bit-twiddling evaluate phase through the
-    Pallas TPU kernels (interpret mode on CPU; real kernels on TPU)."""
+    Pallas TPU kernels (compiled on a TPU, interpreted on the CPU backend;
+    see ``kernels.ops.interpret_mode``)."""
     import os
     return os.environ.get("REPRO_PALLAS", "0") == "1"
 

@@ -211,10 +211,11 @@ class StreamOptimizer:
                                             deadline_s=self._left(), **kw)
             try:
                 eng.run_levels()
-            except Exception:
-                # device-execution failure: re-dispatch the whole flight on
+            except _shard.REDISPATCH_ERRORS as e:
+                # device-runtime failure: re-dispatch the whole flight on
                 # the degenerate single-device path (same members, same
                 # space — bit-identical costs), flag it at finalize
+                _shard.log_redispatch(e, len(members))
                 eng = BatchEngine(members, chunk=chunk, algorithm=space,
                                   pipeline=self.pipeline,
                                   deadline_s=self._left(), **kw)

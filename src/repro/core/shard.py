@@ -63,6 +63,7 @@ test session; ``benchmarks/bench_batch.py --devices N`` does it for itself).
 """
 from __future__ import annotations
 
+import logging
 import time
 from collections import deque
 from math import comb
@@ -88,6 +89,20 @@ from .joingraph import JoinGraph, typed_edge_arrays
 from .plan import Counters, OptimizeResult, extract_plan
 
 BATCH_AXIS = "batch"
+
+# A sharded flight that fails with one of these is re-run on one device
+# (the degenerate 1-device case is bit-identical, tests/test_shard.py): a
+# device-runtime failure, or the fault plane's injected chunk failure.  Any
+# other exception (a shape bug, a bad mesh, a TypeError) propagates.
+REDISPATCH_ERRORS = (jax.errors.JaxRuntimeError, faults.InjectedFault)
+_log = logging.getLogger(__name__)
+
+
+def log_redispatch(err: BaseException, queries: int) -> None:
+    """Say why a sharded flight is being re-run on one device."""
+    _log.warning("sharded flight of %d queries failed on the mesh (%s: %s); "
+                 "re-running it on one device", queries,
+                 type(err).__name__, err)
 
 
 # ============================================================ mesh helpers ==

@@ -517,8 +517,9 @@ def main(argv=None) -> int:
         ap.error("exactly one of --socket / --tcp is required")
 
     # before the first jax import: backends read XLA_FLAGS exactly once
-    from repro.hostdev import ensure_host_devices
+    from repro.hostdev import ensure_compile_cache, ensure_host_devices
     ensure_host_devices(args.devices)
+    ensure_compile_cache()
     faults.install_from_env()          # REPRO_FAULTS= chaos harness, if any
 
     host = port = None

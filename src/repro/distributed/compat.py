@@ -1,32 +1,21 @@
-"""The one shard_map version-compat shim.
+"""The one shard_map call site.
 
-Every shard_map call site in the repo — the batch-axis wrappers in
+Every shard_map use in the repo — the batch-axis wrappers in
 ``core.shard``, the lattice level-commit exchange in
-``distributed.collectives``, the compressed gradient reductions — must
-import ``shard_map_compat`` from here.  ``tests/test_lattice_shard.py``
-pins that with a regression test asserting all import sites resolve to
-this single function object, so the JAX-version shimming cannot fork into
-drift-prone copies again.
+``distributed.collectives``, the compressed gradient reductions — imports
+``shard_map_compat`` from here.  ``tests/test_lattice_shard.py`` pins that
+with a regression test asserting all import sites resolve to this single
+function object, so the replication-check flag cannot drift between
+copies.
 """
 from __future__ import annotations
 
-import inspect
+import jax
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check=False):
-    """shard_map across JAX versions: top-level ``jax.shard_map`` with
-    ``check_vma`` (new) vs ``jax.experimental.shard_map`` with ``check_rep``
-    (<= 0.4.x).  The kwarg is picked by signature inspection so genuine
-    construction errors propagate instead of being retried away."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    if "check_vma" in params:
-        kw = {"check_vma": check}
-    elif "check_rep" in params:
-        kw = {"check_rep": check}
-    else:
-        kw = {}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    """``jax.shard_map`` with its ``check_vma`` replication check set to
+    ``check`` (off by default: the lattice commit returns values that are
+    replicated by construction)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -13,9 +13,9 @@ Per lane (DPSUB/MPDP-general inner enumeration):
     ccp  = lb,rb nonempty & connected(lb) & connected(rb) & cross-edge(lb,rb)
 grow(lb | rb) runs as a fixed NMAX-sweep frontier expansion.
 
-The matching pure-jnp oracle is kernels/ref.py; ops.py wraps pallas_call
-(interpret=True on CPU — this container validates semantics, TPU is the
-performance target).
+The matching pure-jnp oracle is kernels/ref.py.  ``interpret`` has no
+default: ops.py passes it from the backend (interpreted on the CPU test
+backend, compiled through Mosaic on a TPU).
 """
 from __future__ import annotations
 
@@ -213,7 +213,7 @@ def _pad2d(x, rows_blk: int):
 
 @functools.partial(jax.jit, static_argnames=("nmax", "rows_blk", "interpret"))
 def ccp_eval(S, sub, adj, *, nmax: int, rows_blk: int = 32,
-             interpret: bool = True):
+             interpret: bool):
     """(L,) int32 lanes -> (lb, rb, ccp int32) via the Pallas kernel."""
     S2, n = _pad2d(S, rows_blk)
     sub2, _ = _pad2d(sub, rows_blk)
@@ -234,7 +234,7 @@ def ccp_eval(S, sub, adj, *, nmax: int, rows_blk: int = 32,
 
 @functools.partial(jax.jit, static_argnames=("nmax", "rows_blk", "interpret"))
 def connectivity(S, adj, *, nmax: int, rows_blk: int = 32,
-                 interpret: bool = True):
+                 interpret: bool):
     S2, n = _pad2d(S, rows_blk)
     rows = S2.shape[0]
     blk = pl.BlockSpec((rows_blk, LANE), lambda i, *_: (i, 0))
@@ -252,7 +252,7 @@ def connectivity(S, adj, *, nmax: int, rows_blk: int = 32,
 @functools.partial(jax.jit, static_argnames=("nmax", "nb", "rows_blk",
                                              "interpret"))
 def bconnectivity(S, qid, adj_b, *, nmax: int, nb: int, rows_blk: int = 32,
-                  interpret: bool = True):
+                  interpret: bool):
     """(L,) lanes + per-lane query ids -> connectivity against adj_b[qid]."""
     S2, n = _pad2d(S, rows_blk)
     q2, _ = _pad2d(qid, rows_blk)
@@ -272,7 +272,7 @@ def bconnectivity(S, qid, adj_b, *, nmax: int, nb: int, rows_blk: int = 32,
 @functools.partial(jax.jit, static_argnames=("nmax", "nb", "rows_blk",
                                              "interpret"))
 def bccp_eval(S, sub, qid, adj_b, *, nmax: int, nb: int, rows_blk: int = 32,
-              interpret: bool = True):
+              interpret: bool):
     """Batched DPSUB lanes -> (lb, rb, ccp int32) via the Pallas kernel."""
     S2, n = _pad2d(S, rows_blk)
     sub2, _ = _pad2d(sub, rows_blk)
@@ -294,7 +294,7 @@ def bccp_eval(S, sub, qid, adj_b, *, nmax: int, nb: int, rows_blk: int = 32,
 @functools.partial(jax.jit, static_argnames=("nmax", "nb", "rows_blk",
                                              "interpret"))
 def btree_eval(S, ub, vb, qid, adj_b, *, nmax: int, nb: int,
-               rows_blk: int = 32, interpret: bool = True):
+               rows_blk: int = 32, interpret: bool):
     """Batched MPDP:Tree lanes -> (S_left, edge_in int32)."""
     S2, n = _pad2d(S, rows_blk)
     ub2, _ = _pad2d(ub, rows_blk)
@@ -317,7 +317,7 @@ def btree_eval(S, ub, vb, qid, adj_b, *, nmax: int, nb: int,
 @functools.partial(jax.jit, static_argnames=("nmax", "nb", "rows_blk",
                                              "interpret"))
 def bgeneral_eval(S, block, r, qid, adj_b, *, nmax: int, nb: int,
-                  rows_blk: int = 32, interpret: bool = True):
+                  rows_blk: int = 32, interpret: bool):
     """Batched MPDP-general lanes -> (lb, S_left, ccp int32)."""
     S2, n = _pad2d(S, rows_blk)
     blk2, _ = _pad2d(block, rows_blk)
@@ -339,7 +339,7 @@ def bgeneral_eval(S, block, r, qid, adj_b, *, nmax: int, nb: int,
 
 @functools.partial(jax.jit, static_argnames=("nmax", "rows_blk", "interpret"))
 def grow_pair(S, lb, rb, adj, *, nmax: int, rows_blk: int = 32,
-              interpret: bool = True):
+              interpret: bool):
     S2, n = _pad2d(S, rows_blk)
     lb2, _ = _pad2d(lb, rows_blk)
     rb2, _ = _pad2d(rb, rows_blk)

@@ -1,24 +1,22 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute with ``interpret=True`` (Pallas
-interprets the kernel body in Python/XLA — semantics identical, perf not
-representative).  On a real TPU set ``REPRO_PALLAS_INTERPRET=0``.
-``use_pallas()`` gates the engine integration: the XLA lane path stays the
-CPU default; REPRO_PALLAS=1 routes the evaluate phase through these kernels.
+Interpret mode follows the backend: on a TPU the kernels compile through
+Mosaic; on the CPU backend (the test suites) Pallas interprets the kernel
+body (semantics identical, speed not representative).  The engines call
+these wrappers only under REPRO_PALLAS=1 (``core.engine._use_pallas``); the
+XLA lane path stays the default.
 """
 from __future__ import annotations
 
-import os
+import jax
 
 from . import ccp_eval as _k
 
 
 def interpret_mode() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
-
-def use_pallas() -> bool:
-    return os.environ.get("REPRO_PALLAS", "0") == "1"
+    """Interpret the kernels only where Mosaic cannot compile them: the CPU
+    backend.  Never true on a TPU."""
+    return jax.default_backend() == "cpu"
 
 
 def ccp_eval(S, sub, adj, nmax: int):

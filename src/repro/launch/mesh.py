@@ -21,11 +21,8 @@ def _make_mesh(shape, axes):
     # count (core.shard.take_devices raises with the CPU-emulation recipe)
     from ..core.shard import take_devices
     devices = take_devices(n)
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is not None:                 # jax >= 0.5: explicit axis types
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(at.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -222,6 +222,32 @@ class TestChunkFaults:
         assert any(r.info.get("redispatched") for r in rs)
         assert not any("degraded" in r.info for r in rs)
 
+    def test_stream_device_failure_redispatches_bit_identical(self):
+        ref = optimize_many(SMALL, algorithm="dpsub")
+        faults.install(FaultPlan(rules=(FaultRule("chunk", 1),)))
+        rs, _ = optimize_stream(SMALL, config=OptimizerConfig(
+            algorithm="dpsub", devices=4))
+        assert faults.fired() == ["chunk@1:raise"]
+        assert fingerprint(rs) == fingerprint(ref)
+        assert any(r.info.get("redispatched") for r in rs)
+
+    @pytest.mark.parametrize("entry", ["optimize_many", "optimize_stream"])
+    def test_programming_error_is_not_redispatched(self, entry, monkeypatch):
+        """Only device-runtime and injected failures re-run on one device:
+        a bug on the mesh path must surface, not become a 1-device run."""
+        from repro.core import shard
+
+        def broken(self):
+            raise TypeError("shape bug on the mesh path")
+
+        monkeypatch.setattr(shard.ShardedBatchEngine, "run_levels", broken)
+        cfg = OptimizerConfig(algorithm="dpsub", devices=4)
+        with pytest.raises(TypeError, match="shape bug"):
+            if entry == "optimize_many":
+                optimize_many(SMALL, config=cfg)
+            else:
+                optimize_stream(SMALL, config=cfg)
+
     def test_slow_chunk_changes_nothing(self):
         ref = optimize_many(SMALL, algorithm="dpsub")
         faults.install(FaultPlan(rules=(

@@ -40,3 +40,11 @@ def test_connectivity_and_grow_pair_match_ref(g, L):
     g2 = ref.grow_pair_ref(Sd, lb, rb, dg.adj, dg.nmax)
     for a, b in zip(g1, g2):
         assert (np.asarray(a) == np.asarray(b)).all()
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", False)])
+def test_interpret_mode_follows_backend(backend, interpret, monkeypatch):
+    """Pallas interprets only on the CPU backend; a TPU always compiles."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    assert ops.interpret_mode() is interpret
