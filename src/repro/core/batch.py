@@ -80,12 +80,14 @@ from . import faults
 from . import unrank as ur
 from .config import (MAX_FLIGHT, UNSET, OptimizerConfig, alias_kwarg,
                      resolve_config)
-from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap, _merge_best,
-                     _merge_scattered, _prune, _scatter_f32, _scatter_i32,
-                     _typed_lane_cost, _use_pallas, _use_pipeline)
+from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap, _fetch,
+                     _merge_best, _merge_scattered, _prune, _scatter_f32,
+                     _scatter_i32, _typed_lane_cost, _use_pallas,
+                     _use_pipeline)
 from .exec_cache import EXEC
 from .joingraph import JoinGraph, typed_edge_arrays
 from .plan import Counters, OptimizeResult, extract_plan, leaf_plan
+from .telemetry import span
 
 NMAX_BATCH = 16          # memo is (bcap << NMAX): past 16 fall back to solo
 MAX_BATCH = MAX_FLIGHT   # sub-batch cap: bounds memo memory + recompiles
@@ -344,25 +346,37 @@ class _LevelLoop:
         """Run the level-synchronous DP; the memo stays on device (fetch it
         with ``collect``).  The pipelined driver produces bit-identical memo
         contents — same chunk grids, same kernels, same merge order — it
-        only overlaps host compaction with in-flight device work."""
+        only overlaps host compaction with in-flight device work.
+
+        Spans: ``engine.levels`` around the loop, one ``level.filter``,
+        ``level.register``, ``level.pairs`` (general space) and
+        ``level.eval`` per step of a level, ``level.fetch`` inside them
+        around each blocking fetch (the engines' drains)."""
         t0 = time.perf_counter()
         max_n = max(g.n for g in self.graphs)
         general = self.algorithm == "mpdp_general"
         self._arm_deadline()
-        if self.pipeline:
-            self._run_levels_pipelined(max_n, general)
-        else:
-            for i in range(2, max_n + 1):
-                if self._expired(i, max_n):
-                    break
-                sets = self._filter_collect(self._filter_dispatch(i))
-                self._register_level(i, sets)
-                if general:
-                    ctx = self._eval_general_dispatch(
-                        i, sets, self._pairs_level(sets))
-                    self._eval_general_finalize(i, sets, ctx)
-                else:
-                    self._eval_finalize(i, sets, self._eval_dispatch(i, sets))
+        with span("engine.levels"):
+            if self.pipeline:
+                self._run_levels_pipelined(max_n, general)
+            else:
+                for i in range(2, max_n + 1):
+                    if self._expired(i, max_n):
+                        break
+                    with span("level.filter"):
+                        sets = self._filter_collect(self._filter_dispatch(i))
+                    with span("level.register"):
+                        self._register_level(i, sets)
+                    if general:
+                        with span("level.pairs"):
+                            pairs = self._pairs_level(sets)
+                        with span("level.eval"):
+                            ctx = self._eval_general_dispatch(i, sets, pairs)
+                            self._eval_general_finalize(i, sets, ctx)
+                    else:
+                        with span("level.eval"):
+                            self._eval_finalize(i, sets,
+                                                self._eval_dispatch(i, sets))
         self._wall += time.perf_counter() - t0
 
     def _run_levels_pipelined(self, max_n: int, general: bool) -> None:
@@ -376,33 +390,50 @@ class _LevelLoop:
              buffers eval(i) only reads; stream order keeps them safe), and
              run phase A for the general space — the host-bound stage;
           4. only then sync on eval(i)'s tail, merge and commit.
+
+        Each step gets its own span, so a level shows two ``level.filter``
+        and two ``level.eval`` spans (dispatch, then collect/finalize).
         """
-        sets = self._filter_collect(self._filter_dispatch(2))
-        self._register_level(2, sets)
-        pairs = self._pairs_level(sets) if general else None
+        with span("level.filter"):
+            sets = self._filter_collect(self._filter_dispatch(2))
+        with span("level.register"):
+            self._register_level(2, sets)
+        pairs = None
+        if general:
+            with span("level.pairs"):
+                pairs = self._pairs_level(sets)
         for i in range(2, max_n + 1):
             if self._expired(i, max_n):
                 break
-            fpend = self._filter_dispatch(i + 1) if i < max_n else None
-            if general:
-                ctx = self._eval_general_dispatch(i, sets, pairs)
-            else:
-                ctx = self._eval_dispatch(i, sets)
+            fpend = None
+            if i < max_n:
+                with span("level.filter"):
+                    fpend = self._filter_dispatch(i + 1)
+            with span("level.eval"):
+                if general:
+                    ctx = self._eval_general_dispatch(i, sets, pairs)
+                else:
+                    ctx = self._eval_dispatch(i, sets)
             nxt = nxt_pairs = None
             if fpend is not None:
-                nxt = self._filter_collect(fpend)
-                self._register_level(i + 1, nxt)
+                with span("level.filter"):
+                    nxt = self._filter_collect(fpend)
+                with span("level.register"):
+                    self._register_level(i + 1, nxt)
                 if general:
-                    nxt_pairs = self._pairs_level(nxt)
-            if general:
-                self._eval_general_finalize(i, sets, ctx)
-            else:
-                self._eval_finalize(i, sets, ctx)
+                    with span("level.pairs"):
+                        nxt_pairs = self._pairs_level(nxt)
+            with span("level.eval"):
+                if general:
+                    self._eval_general_finalize(i, sets, ctx)
+                else:
+                    self._eval_finalize(i, sets, ctx)
             sets, pairs = nxt, nxt_pairs
 
     def run(self) -> list[OptimizeResult]:
         self.run_levels()
-        return self.collect()
+        with span("engine.collect"):
+            return self.collect()
 
 
 class BatchEngine(_LevelLoop):
@@ -462,52 +493,54 @@ class BatchEngine(_LevelLoop):
         self.chunk = chunk
         self.size = 1 << self.nmax
         self.flat = self.bcap << self.nmax
-        self.binom = jnp.asarray(ur.binom_table(self.nmax))
-        adj = np.zeros((self.bcap, self.nmax), np.int32)
-        for q, g in enumerate(graphs):
-            for (u, v) in g.edges:
-                adj[q, u] |= 1 << v
-                adj[q, v] |= 1 << u
-        self.adj_b = jnp.asarray(adj)
-        # per-query edge arrays: endpoint bitmaps (tree lane decode) and
-        # endpoint indices (general phase A), stacked on a shared EMAX bucket
-        max_m = max(g.m for g in graphs)
-        self.emax = max(8, int(np.ceil(max(max_m, 1) / 8.0)) * 8)
-        emu = np.zeros((self.bcap, self.emax), np.int32)
-        emv = np.zeros((self.bcap, self.emax), np.int32)
-        eui = np.full((self.bcap, self.emax), -1, np.int32)
-        evi = np.full((self.bcap, self.emax), -1, np.int32)
-        eliv = np.zeros((self.bcap, self.emax), bool)
-        for q, g in enumerate(graphs):
-            for i, (u, v) in enumerate(g.edges):
-                emu[q, i] = 1 << u
-                emv[q, i] = 1 << v
-                eui[q, i], evi[q, i], eliv[q, i] = u, v, True
-        self.emu_b = jnp.asarray(emu)
-        self.emv_b = jnp.asarray(emv)
-        self.eu_idx_b = jnp.asarray(eui)
-        self.ev_idx_b = jnp.asarray(evi)
-        self.edge_live_b = jnp.asarray(eliv)
-        # typed-edge conflict channel: stacked (bcap, emax) kind / operand /
-        # TES arrays, present only when some query has a non-inner edge.
-        # Inner-only batches pass no extra args and carry typed=False, so
-        # their kernel traces (and bits) are exactly the pre-typed ones.
-        self.typed = any(g.typed for g in graphs)
-        if self.typed:
-            tarr = [np.zeros((self.bcap, self.emax), np.int32)
-                    for _ in range(5)]
+        with span("engine.setup"):
+            self.binom = jnp.asarray(ur.binom_table(self.nmax))
+            adj = np.zeros((self.bcap, self.nmax), np.int32)
             for q, g in enumerate(graphs):
-                for a, col in zip(tarr, typed_edge_arrays(g, self.emax)):
-                    a[q] = col
-            self._targs = tuple(jnp.asarray(a) for a in tarr)
-        else:
-            self._targs = ()
-        self.m_b = jnp.asarray(
-            np.array([g.m for g in graphs] + [0] * (self.bcap - self.B),
-                     np.int32))
-        self.counters = [Counters() for _ in graphs]
-        self.timings: dict[str, float] = {}
-        self._init_memo()
+                for (u, v) in g.edges:
+                    adj[q, u] |= 1 << v
+                    adj[q, v] |= 1 << u
+            self.adj_b = jnp.asarray(adj)
+            # per-query edge arrays: endpoint bitmaps (tree lane decode) and
+            # endpoint indices (general phase A), stacked on a shared EMAX
+            # bucket
+            max_m = max(g.m for g in graphs)
+            self.emax = max(8, int(np.ceil(max(max_m, 1) / 8.0)) * 8)
+            emu = np.zeros((self.bcap, self.emax), np.int32)
+            emv = np.zeros((self.bcap, self.emax), np.int32)
+            eui = np.full((self.bcap, self.emax), -1, np.int32)
+            evi = np.full((self.bcap, self.emax), -1, np.int32)
+            eliv = np.zeros((self.bcap, self.emax), bool)
+            for q, g in enumerate(graphs):
+                for i, (u, v) in enumerate(g.edges):
+                    emu[q, i] = 1 << u
+                    emv[q, i] = 1 << v
+                    eui[q, i], evi[q, i], eliv[q, i] = u, v, True
+            self.emu_b = jnp.asarray(emu)
+            self.emv_b = jnp.asarray(emv)
+            self.eu_idx_b = jnp.asarray(eui)
+            self.ev_idx_b = jnp.asarray(evi)
+            self.edge_live_b = jnp.asarray(eliv)
+            # typed-edge conflict channel: stacked (bcap, emax) kind /
+            # operand / TES arrays, present only when some query has a
+            # non-inner edge.  Inner-only batches pass no extra args and
+            # carry typed=False, so their kernel traces (and bits) are
+            # exactly the pre-typed ones.
+            self.typed = any(g.typed for g in graphs)
+            if self.typed:
+                tarr = [np.zeros((self.bcap, self.emax), np.int32)
+                        for _ in range(5)]
+                for q, g in enumerate(graphs):
+                    for a, col in zip(tarr, typed_edge_arrays(g, self.emax)):
+                        a[q] = col
+                self._targs = tuple(jnp.asarray(a) for a in tarr)
+            else:
+                self._targs = ()
+            self.m_b = jnp.asarray(
+                np.array([g.m for g in graphs] + [0] * (self.bcap - self.B),
+                         np.int32))
+            self.counters = [Counters() for _ in graphs]
+            self._init_memo()
 
     # ------------------------------------------------------------- memo ----
     def _init_memo(self):
@@ -586,7 +619,6 @@ class BatchEngine(_LevelLoop):
         accumulators as newer ones execute).  The final fetch is
         ``_filter_collect``'s job, so the pipelined driver can slot the
         tail compaction under the level's evaluate."""
-        t0 = time.perf_counter()
         totals = np.array([comb(g.n, i) if g.n >= i else 0
                            for g in self.graphs], np.int64)
         foff = np.zeros(self.B + 1, np.int64)
@@ -605,8 +637,6 @@ class BatchEngine(_LevelLoop):
             faults.fire("chunk")
             self.chunks_dispatched += 1
             self._filter_drain(ctx, self.pend_window)
-        self.timings["filter"] = (self.timings.get("filter", 0.0)
-                                  + time.perf_counter() - t0)
         return ctx
 
     def _filter_drain(self, ctx: dict, limit: int) -> None:
@@ -614,10 +644,11 @@ class BatchEngine(_LevelLoop):
         pend, per_q = ctx["pend"], ctx["per_q"]
         while len(pend) > limit:
             S, conn, qid = pend.popleft()
-            c = np.asarray(conn)
+            c = _fetch(conn)
             if c.any():
-                Sc = np.asarray(S)[c]
-                qc = np.asarray(qid)[c]
+                S, qid = _fetch((S, qid))
+                Sc = S[c]
+                qc = qid[c]
                 for q in np.unique(qc):
                     per_q[q].append(Sc[qc == q])
 
@@ -625,17 +656,13 @@ class BatchEngine(_LevelLoop):
         """Drain the remaining filter chunks and build the per-query set
         lists (in pipelined mode this runs under device evaluate of the
         previous level)."""
-        t0 = time.perf_counter()
         self._filter_drain(ctx, 0)
         sets_by_q = [np.concatenate(l) if l else np.zeros(0, np.int32)
                      for l in ctx["per_q"]]
-        self.timings["filter"] = (self.timings.get("filter", 0.0)
-                                  + time.perf_counter() - t0)
         return sets_by_q
 
     def _register_level(self, i: int, sets_by_q: list[np.ndarray]) -> None:
         """Host rows (canonical helper) + all_sets/memo_rows registration."""
-        t0 = time.perf_counter()
         idx_l, rows_l, pos_l, set_l = [], [], [], []
         for q, sets_q in enumerate(sets_by_q):
             self._level_off[q][i] = self._next_off[q]
@@ -652,8 +679,6 @@ class BatchEngine(_LevelLoop):
         if idx_l:
             self._scatter(np.concatenate(idx_l), rows=np.concatenate(rows_l))
             self._set_all_sets(np.concatenate(pos_l), np.concatenate(set_l))
-        self.timings["filter"] = (self.timings.get("filter", 0.0)
-                                  + time.perf_counter() - t0)
 
     # ---------------------------------------------------------- evaluate ---
     def _commit_best(self, sets_by_q, best_cost, best_left) -> None:
@@ -690,7 +715,6 @@ class BatchEngine(_LevelLoop):
         total = int(eoff[-1])
         if total == 0:
             return None
-        t0 = time.perf_counter()
         soff = np.zeros(self.B + 1, np.int64)
         np.cumsum(ns, out=soff[1:])
         loff = np.zeros(self.bcap, np.int64)
@@ -734,8 +758,6 @@ class BatchEngine(_LevelLoop):
             faults.fire("chunk")
             self.chunks_dispatched += 1
             self._eval_drain(ctx, self.pend_window)
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
         return ctx
 
     def _eval_drain(self, ctx: dict, limit: int) -> None:
@@ -744,31 +766,27 @@ class BatchEngine(_LevelLoop):
         identical to the synchronous path)."""
         pend = ctx["pend"]
         while len(pend) > limit:
-            seg0, (sc, sl, ev_q, ccp_q) = pend.popleft()
-            ctx["ev"] += np.asarray(ev_q)[: self.B]
-            ctx["ccp"] += np.asarray(ccp_q)[: self.B]
-            _merge_best(ctx["best_cost"], ctx["best_left"], seg0,
-                        np.asarray(sc), np.asarray(sl))
+            seg0, out = pend.popleft()
+            sc, sl, ev_q, ccp_q = _fetch(out)
+            ctx["ev"] += ev_q[: self.B]
+            ctx["ccp"] += ccp_q[: self.B]
+            _merge_best(ctx["best_cost"], ctx["best_left"], seg0, sc, sl)
 
     def _eval_finalize(self, i: int, sets_by_q: list[np.ndarray], ctx) -> None:
         """Drain the level's remaining chunk results and commit the level's
         best (cost, left) per set to the memo."""
         if ctx is None:
             return
-        t0 = time.perf_counter()
         self._eval_drain(ctx, 0)
         for q in range(self.B):
             self.counters[q].evaluated += int(ctx["ev"][q])
             self.counters[q].ccp += int(ctx["ccp"][q])
         self._commit_best(sets_by_q, ctx["best_cost"], ctx["best_left"])
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
 
     # ------------------------------------------------- MPDP-general phase --
     def _pairs_level(self, sets_by_q: list[np.ndarray]):
         """Phase A per query (shared ``blocks.np_pairs_for_sets`` driver),
         fused into global (set, block, qid, segment) pair arrays."""
-        t0 = time.perf_counter()
         soff = 0
         ps_l, pb_l, pq_l, pk_l = [], [], [], []
         for q, sets_q in enumerate(sets_by_q):
@@ -784,8 +802,6 @@ class BatchEngine(_LevelLoop):
             # sets_q is ascending (colex rank order == ascending bitmap)
             pk_l.append(soff + np.searchsorted(sets_q, ps_q).astype(np.int64))
             soff += len(sets_q)
-        self.timings["blocks"] = (self.timings.get("blocks", 0.0)
-                                  + time.perf_counter() - t0)
         if not ps_l:
             z = np.zeros(0, np.int32)
             return z, z, z, np.zeros(0, np.int64)
@@ -799,7 +815,6 @@ class BatchEngine(_LevelLoop):
         ps, pb, pq, pk = pairs
         if not len(ps):
             return None
-        t0 = time.perf_counter()
         sizes = bs.np_popcount(pb).astype(np.int64)
         lane_sz = (np.int64(1) << sizes).astype(np.int64)
         offs = np.zeros(len(ps) + 1, np.int64)
@@ -837,8 +852,6 @@ class BatchEngine(_LevelLoop):
             faults.fire("chunk")
             self.chunks_dispatched += 1
             self._eval_general_drain(ctx, self.pend_window)
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
         return ctx
 
     def _eval_general_drain(self, ctx: dict, limit: int) -> None:
@@ -846,20 +859,20 @@ class BatchEngine(_LevelLoop):
         per-pair candidates for the scattered merge."""
         pend, pk = ctx["pend"], ctx["pk"]
         while len(pend) > limit:
-            p0, npair, (sc, sl, ev_q, ccp_q) = pend.popleft()
-            ctx["ev"] += np.asarray(ev_q)[: self.B]
-            ctx["ccp"] += np.asarray(ccp_q)[: self.B]
-            scn = np.asarray(sc)[:npair]
+            p0, npair, out = pend.popleft()
+            sc, sl, ev_q, ccp_q = _fetch(out)
+            ctx["ev"] += ev_q[: self.B]
+            ctx["ccp"] += ccp_q[: self.B]
+            scn = sc[:npair]
             fin = np.isfinite(scn)
             ctx["k"].append(pk[p0: p0 + npair][fin])
             ctx["c"].append(scn[fin])
-            ctx["l"].append(np.asarray(sl)[:npair][fin])
+            ctx["l"].append(sl[:npair][fin])
 
     def _eval_general_finalize(self, i: int, sets_by_q: list[np.ndarray],
                                ctx) -> None:
         if ctx is None:
             return
-        t0 = time.perf_counter()
         self._eval_general_drain(ctx, 0)
         best_cost = np.full(ctx["total_sets"], INF, np.float32)
         best_left = np.zeros(ctx["total_sets"], np.int32)
@@ -871,8 +884,6 @@ class BatchEngine(_LevelLoop):
                              np.concatenate(ctx["c"]),
                              np.concatenate(ctx["l"]))
         self._commit_best(sets_by_q, best_cost, best_left)
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
 
     # ------------------------------------------------------------ driver ---
     def collect(self) -> list[OptimizeResult]:
@@ -880,8 +891,7 @@ class BatchEngine(_LevelLoop):
         the streaming service this host-only finalize is deferred so it
         overlaps the next flight's device work."""
         t0 = time.perf_counter()
-        cost_all = np.asarray(self.memo_cost)
-        left_all = np.asarray(self.memo_left)
+        cost_all, left_all = _fetch((self.memo_cost, self.memo_left))
         out = []
         wall = self._wall + time.perf_counter() - t0
         for q, g in enumerate(self.graphs):
@@ -908,7 +918,6 @@ class BatchEngine(_LevelLoop):
                 r.info["degraded"] = {**self.degraded, **dinfo}
             else:
                 raise RuntimeError(f"no plan found for batch query {q}")
-            r.timings = dict(self.timings)
             out.append(r)
         return out
 
@@ -1100,12 +1109,13 @@ def optimize_many(graphs: list[JoinGraph], algorithm=UNSET, chunk=UNSET,
         shard_mesh = _shard.batch_mesh(
             cfg.mesh if cfg.mesh is not None else cfg.devices)
     results: list[OptimizeResult | None] = [None] * len(graphs)
-    pending = probe_stream(graphs, results, cache, algorithm)
-    pending, deferred, dup_rep = dedup_pending(graphs, pending, cache)
-    buckets, solo = bucket_pending(graphs, pending, algorithm)
     lattice: list[tuple[int, str]] = []
-    if shard_mesh is not None:
-        lattice, solo = lattice_pending(graphs, solo, algorithm)
+    with span("service.admit"):
+        pending = probe_stream(graphs, results, cache, algorithm)
+        pending, deferred, dup_rep = dedup_pending(graphs, pending, cache)
+        buckets, solo = bucket_pending(graphs, pending, algorithm)
+        if shard_mesh is not None:
+            lattice, solo = lattice_pending(graphs, solo, algorithm)
 
     # one absolute deadline for the whole stream: each engine gets the time
     # still remaining, so sequential buckets share the budget instead of
@@ -1165,14 +1175,15 @@ def optimize_many(graphs: list[JoinGraph], algorithm=UNSET, chunk=UNSET,
                 adaptive.observe(b, space, run_space, _tele.capture(
                     eng, rs, nmax=b, queries=len(group),
                     wall_s=time.perf_counter() - t_fl))
-            for qi, r in zip(group, rs):
-                if redispatched:
-                    r.info["redispatched"] = True
-                results[qi] = r
-                # degraded plans are best-effort, never cached: a later
-                # undegraded run must not hit a deadline-truncated plan
-                if cache is not None and "degraded" not in r.info:
-                    cache.put(graphs[qi], r)
+            with span("service.finalize"):
+                for qi, r in zip(group, rs):
+                    if redispatched:
+                        r.info["redispatched"] = True
+                    results[qi] = r
+                    # degraded plans are best-effort, never cached: a later
+                    # undegraded run must not hit a deadline-truncated plan
+                    if cache is not None and "degraded" not in r.info:
+                        cache.put(graphs[qi], r)
     for qi, space in lattice:
         from .lattice import LatticeShardedEngine
         r = LatticeShardedEngine(graphs[qi], shard_mesh, chunk=chunk,

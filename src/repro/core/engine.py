@@ -42,6 +42,7 @@ from .config import (CHUNK, CYC_CAP_DEFAULT, UNSET, OptimizerConfig,
                      alias_kwarg, resolve_config)
 from .joingraph import DeviceGraph, JoinGraph
 from .plan import Counters, OptimizeResult, extract_plan
+from .telemetry import span
 
 INF = np.float32(np.inf)
 
@@ -61,6 +62,14 @@ def _use_pipeline() -> bool:
     bit-identical to the synchronous default — only dispatch order changes."""
     import os
     return os.environ.get("REPRO_PIPELINE", "0") == "1"
+
+
+def _fetch(tree):
+    """Blocking device-to-host copy of ``tree`` (an array or a tuple of
+    them) under a ``level.fetch`` span: one host round trip of a level
+    loop."""
+    with span("level.fetch"):
+        return jax.device_get(tree)
 
 
 def _cap(n: int, lo: int = 1024) -> int:
@@ -333,26 +342,27 @@ class ExactEngine:
         self.chunk = chunk
         self.cyc_cap = cyc_cap
         self.size = 1 << self.nmax
-        self.binom = jnp.asarray(ur.binom_table(self.nmax))
-        # edge vertex indices (for block finding)
-        eu = np.full(self.emax, -1, np.int32)
-        ev = np.full(self.emax, -1, np.int32)
-        lv = np.zeros(self.emax, bool)
-        for i, (u, v) in enumerate(g.edges):
-            eu[i], ev[i], lv[i] = u, v, True
-        self.eu_idx = jnp.asarray(eu)
-        self.ev_idx = jnp.asarray(ev)
-        self.edge_live = jnp.asarray(lv)
-        # typed-edge conflict arrays: passed to the eval kernels (with the
-        # typed=True static) only when the query has non-inner edges, so the
-        # inner-only trace stays byte-identical to the pre-typed engine
-        self.typed = g.typed
-        self._targs = ((self.dg.ekind, self.dg.elm, self.dg.erm,
-                        self.dg.etes_l, self.dg.etes_r)
-                       if self.typed else (None,) * 5)
-        self.counters = Counters()
-        self.timings: dict[str, float] = {}
-        self._init_memo()
+        with span("engine.setup"):
+            self.binom = jnp.asarray(ur.binom_table(self.nmax))
+            # edge vertex indices (for block finding)
+            eu = np.full(self.emax, -1, np.int32)
+            ev = np.full(self.emax, -1, np.int32)
+            lv = np.zeros(self.emax, bool)
+            for i, (u, v) in enumerate(g.edges):
+                eu[i], ev[i], lv[i] = u, v, True
+            self.eu_idx = jnp.asarray(eu)
+            self.ev_idx = jnp.asarray(ev)
+            self.edge_live = jnp.asarray(lv)
+            # typed-edge conflict arrays: passed to the eval kernels (with
+            # the typed=True static) only when the query has non-inner
+            # edges, so the inner-only trace stays byte-identical to the
+            # pre-typed engine
+            self.typed = g.typed
+            self._targs = ((self.dg.ekind, self.dg.elm, self.dg.erm,
+                            self.dg.etes_l, self.dg.etes_r)
+                           if self.typed else (None,) * 5)
+            self.counters = Counters()
+            self._init_memo()
 
     # ------------------------------------------------------------- memo ----
     def _init_memo(self):
@@ -394,27 +404,28 @@ class ExactEngine:
     # ------------------------------------------------------------ filter ---
     def _level_sets(self, i: int):
         """Connected sets of level i (unrank+filter, or frontier expansion)."""
-        t0 = time.perf_counter()
-        if self.enum == "expand":
-            sets_np = self._level_sets_expand(i)
-        else:
-            sets_np = self._level_sets_unrank(i)
-        rows_np = cm.np_rows_for_sets(sets_np, self.g)
+        with span("level.filter"):
+            if self.enum == "expand":
+                sets_np = self._level_sets_expand(i)
+            else:
+                sets_np = self._level_sets_unrank(i)
         self._prev_level = sets_np
         # scatter rows for this level; register in the packed level buffer
         if len(sets_np):
-            self._scatter(sets_np, rows=rows_np)
-            cap = _cap(len(sets_np))
-            buf = np.zeros(cap, np.int32)
-            buf[: len(sets_np)] = sets_np
-            pos = np.full(cap, self.size, np.int32)
-            pos[: len(sets_np)] = self._next_off + np.arange(len(sets_np))
-            self.all_sets = _scatter_i32(self.all_sets, jnp.asarray(pos),
-                                         jnp.asarray(buf), size=self.size, cap=cap)
+            with span("level.register"):
+                self._scatter(sets_np,
+                              rows=cm.np_rows_for_sets(sets_np, self.g))
+                cap = _cap(len(sets_np))
+                buf = np.zeros(cap, np.int32)
+                buf[: len(sets_np)] = sets_np
+                pos = np.full(cap, self.size, np.int32)
+                pos[: len(sets_np)] = self._next_off + np.arange(len(sets_np))
+                self.all_sets = _scatter_i32(self.all_sets, jnp.asarray(pos),
+                                             jnp.asarray(buf), size=self.size,
+                                             cap=cap)
         self.level_off[i] = self._next_off
         self.level_cnt[i] = len(sets_np)
         self._next_off += len(sets_np)
-        self.timings["filter"] = self.timings.get("filter", 0.0) + time.perf_counter() - t0
         return sets_np
 
     def _level_sets_unrank(self, i: int):
@@ -425,9 +436,9 @@ class ExactEngine:
             S, conn = _filter_chunk(
                 jnp.int32(rank0), jnp.int32(total), jnp.int32(i), self.binom,
                 self.dg.adj, nmax=self.nmax, chunk=self.chunk)
-            c = np.asarray(conn)
+            c = _fetch(conn)
             if c.any():
-                sets_l.append(np.asarray(S)[c])
+                sets_l.append(_fetch(S)[c])
         if sets_l:
             return np.concatenate(sets_l)
         return np.zeros(0, np.int32)
@@ -450,7 +461,7 @@ class ExactEngine:
             pad[: len(sl)] = sl
             cand = _expand_chunk(jnp.asarray(pad), jnp.int32(len(sl)),
                                  self.dg.adj, nmax=self.nmax, cap=cap)
-            c = np.asarray(cand).ravel()
+            c = _fetch(cand).ravel()
             cand_l.append(c[c != 0])
         return np.unique(np.concatenate(cand_l)) if cand_l else np.zeros(0, np.int32)
 
@@ -478,16 +489,26 @@ class ExactEngine:
                          "levels_done": i - 1, "levels_total": self.n}
         return True
 
+    def _run_levels(self, eval_level) -> None:
+        """The solo level loop: connected sets of each level, then
+        ``eval_level(i, sets)`` evaluates and commits them, under an
+        ``engine.levels`` span (the batched engines' ``_LevelLoop`` names
+        its steps alike)."""
+        self._arm_deadline()
+        with span("engine.levels"):
+            for i in range(2, self.n + 1):
+                if self._expired(i):
+                    break
+                sets_np = self._level_sets(i)
+                if len(sets_np):
+                    eval_level(i, sets_np)
+
     # -------------------------------------------------------------- DPSUB --
     def run_dpsub(self) -> None:
-        self._arm_deadline()
-        for i in range(2, self.n + 1):
-            if self._expired(i):
-                break
-            sets_np = self._level_sets(i)
-            if not len(sets_np):
-                continue
-            t0 = time.perf_counter()
+        self._run_levels(self._eval_dpsub)
+
+    def _eval_dpsub(self, i: int, sets_np) -> None:
+        with span("level.eval"):
             ns = len(sets_np)
             lanes = ns << i
             best_cost = np.full(ns, INF, np.float32)
@@ -501,24 +522,19 @@ class ExactEngine:
                     self.dg.adj, self.memo_cost, self.memo_rows, *self._targs,
                     nmax=self.nmax, chunk=self.chunk, nseg=self.chunk + 1,
                     typed=self.typed)
+                sc, sl, ev, cc = _fetch((sc, sl, ev, cc))
                 self.counters.evaluated += int(ev)
                 self.counters.ccp += int(cc)
-                _merge_best(best_cost, best_left, lane0 >> i,
-                            np.asarray(sc), np.asarray(sl))
+                _merge_best(best_cost, best_left, lane0 >> i, sc, sl)
             self._commit_level(sets_np, best_cost, best_left)
-            self.timings["evaluate"] = self.timings.get("evaluate", 0.0) + time.perf_counter() - t0
 
     # ---------------------------------------------------------- MPDP tree --
     def run_mpdp_tree(self) -> None:
+        self._run_levels(self._eval_mpdp_tree)
+
+    def _eval_mpdp_tree(self, i: int, sets_np) -> None:
         m = self.g.m
-        self._arm_deadline()
-        for i in range(2, self.n + 1):
-            if self._expired(i):
-                break
-            sets_np = self._level_sets(i)
-            if not len(sets_np):
-                continue
-            t0 = time.perf_counter()
+        with span("level.eval"):
             ns = len(sets_np)
             lanes = ns * m
             best_cost = np.full(ns, INF, np.float32)
@@ -533,37 +549,31 @@ class ExactEngine:
                     self.memo_cost, self.memo_rows, *self._targs,
                     nmax=self.nmax, chunk=self.chunk, nseg=self.chunk + 1,
                     typed=self.typed)
+                sc, sl, ev, cc = _fetch((sc, sl, ev, cc))
                 self.counters.evaluated += int(ev)
                 self.counters.ccp += int(cc)
-                _merge_best(best_cost, best_left, lane0 // m,
-                            np.asarray(sc), np.asarray(sl))
+                _merge_best(best_cost, best_left, lane0 // m, sc, sl)
             self._commit_level(sets_np, best_cost, best_left)
-            self.timings["evaluate"] = self.timings.get("evaluate", 0.0) + time.perf_counter() - t0
 
     # ------------------------------------------------------- MPDP general --
     def _find_blocks_host(self, sets_np):
         """Phase A: per-set blocks -> compacted (set, block) pair arrays
         (shared host driver in ``blocks.np_pairs_for_sets``)."""
-        t0 = time.perf_counter()
         ps, pb = bl.np_pairs_for_sets(
             sets_np, self.g, self.dg.adj, self.eu_idx, self.ev_idx,
             self.edge_live, nmax=self.nmax, emax=self.emax,
             cyc_cap=self.cyc_cap)
-        self.timings["blocks"] = self.timings.get("blocks", 0.0) + time.perf_counter() - t0
         return ps, pb
 
     def run_mpdp_general(self) -> None:
-        self._arm_deadline()
-        for i in range(2, self.n + 1):
-            if self._expired(i):
-                break
-            sets_np = self._level_sets(i)
-            if not len(sets_np):
-                continue
+        self._run_levels(self._eval_mpdp_general)
+
+    def _eval_mpdp_general(self, i: int, sets_np) -> None:
+        with span("level.pairs"):
             ps, pb = self._find_blocks_host(sets_np)
-            if not len(ps):
-                continue
-            t0 = time.perf_counter()
+        if not len(ps):
+            return
+        with span("level.eval"):
             sizes = bs.np_popcount(pb).astype(np.int64)
             lane_sz = (1 << sizes).astype(np.int64)
             offs = np.zeros(len(ps) + 1, np.int64)
@@ -594,18 +604,18 @@ class ExactEngine:
                     self.dg.adj, self.memo_cost, self.memo_rows, *self._targs,
                     nmax=self.nmax, chunk=self.chunk, pcap=pcap,
                     typed=self.typed)
+                sc, sl, ev, cc = _fetch((sc, sl, ev, cc))
                 self.counters.evaluated += int(ev)
                 self.counters.ccp += int(cc)
-                scn = np.asarray(sc)[:npair]
+                scn = sc[:npair]
                 fin = np.isfinite(scn)
                 k_all.append(pk[p0:p1][fin])
                 c_all.append(scn[fin])
-                l_all.append(np.asarray(sl)[:npair][fin])
+                l_all.append(sl[:npair][fin])
             if k_all:
                 _merge_scattered(best_cost, best_left, np.concatenate(k_all),
                                  np.concatenate(c_all), np.concatenate(l_all))
             self._commit_level(sets_np, best_cost, best_left)
-            self.timings["evaluate"] = self.timings.get("evaluate", 0.0) + time.perf_counter() - t0
 
     # ------------------------------------------------------------- DPSIZE --
     def run_dpsize(self) -> None:
@@ -613,14 +623,10 @@ class ExactEngine:
             raise ValueError(
                 "dpsize does not support non-inner join edges (use dpsub / "
                 "mpdp / dpccp — the conflict-masked lane spaces)")
-        level_sets: dict[int, np.ndarray] = {1: np.array([1 << v for v in range(self.n)], np.int32)}
-        self._arm_deadline()
-        for i in range(2, self.n + 1):
-            if self._expired(i):
-                break
-            sets_np = self._level_sets(i)
-            level_sets[i] = sets_np
-            t0 = time.perf_counter()
+        self._run_levels(self._eval_dpsize)
+
+    def _eval_dpsize(self, i: int, sets_np) -> None:
+        with span("level.eval"):
             s_all, c_all, l_all = [], [], []
             for a in range(1, i):
                 b = i - a
@@ -638,13 +644,13 @@ class ExactEngine:
                         self.memo_rows, self.dg.card_l2, self.dg.emask_u,
                         self.dg.emask_v, self.dg.esel_l2,
                         nmax=self.nmax, chunk=self.chunk)
+                    S, cn, A, ev, cc = _fetch((S, cand, A, ev, cc))
                     self.counters.evaluated += int(ev)
                     self.counters.ccp += int(cc)
-                    cn = np.asarray(cand)
                     fin = np.isfinite(cn)
-                    s_all.append(np.asarray(S)[fin])
+                    s_all.append(S[fin])
                     c_all.append(cn[fin])
-                    l_all.append(np.asarray(A)[fin])
+                    l_all.append(A[fin])
             if s_all:
                 ss = np.concatenate(s_all).astype(np.int64)
                 scratch_c = np.full(1 << self.n, INF, np.float32)
@@ -653,15 +659,13 @@ class ExactEngine:
                                  np.concatenate(c_all), np.concatenate(l_all))
                 ks = np.flatnonzero(np.isfinite(scratch_c)).astype(np.int32)
                 self._scatter(ks, cost=scratch_c[ks], left=scratch_l[ks])
-            self.timings["evaluate"] = self.timings.get("evaluate", 0.0) + time.perf_counter() - t0
 
     # ------------------------------------------------------------ finish ---
     def result(self, algorithm: str, t0: float) -> OptimizeResult:
         full = self.g.full_set
-        cost = float(np.asarray(self.memo_cost[full]))
+        cost = float(_fetch(self.memo_cost[full]))
         if np.isfinite(cost):
-            left_np = np.asarray(self.memo_left)
-            p = extract_plan(full, left_np, self.g)
+            p = extract_plan(full, _fetch(self.memo_left), self.g)
             return OptimizeResult(plan=p, cost=cost, counters=self.counters,
                                   algorithm=algorithm,
                                   wall_s=time.perf_counter() - t0,
@@ -671,8 +675,8 @@ class ExactEngine:
         # deadline expired before the full set was memoized: stitch the
         # committed memo prefix with a GOO completion (anytime contract)
         from ..heuristics.idp import stitch_partial_memo
-        p, c, dinfo = stitch_partial_memo(self.g, np.asarray(self.memo_cost),
-                                          np.asarray(self.memo_left))
+        p, c, dinfo = stitch_partial_memo(
+            self.g, *_fetch((self.memo_cost, self.memo_left)))
         r = OptimizeResult(plan=p, cost=c, counters=self.counters,
                            algorithm=algorithm,
                            wall_s=time.perf_counter() - t0,
@@ -741,9 +745,8 @@ def optimize(g: JoinGraph, algorithm=UNSET, chunk=UNSET, cyc_cap=UNSET,
         eng.run_dpsize()
     else:
         raise ValueError(f"unknown algorithm {algorithm!r}")
-    res = eng.result(algo, t0)
-    res.timings = dict(eng.timings)
-    return res
+    with span("engine.collect"):
+        return eng.result(algo, t0)
 
 
 def optimize_many(graphs, algorithm=UNSET, chunk=UNSET, cache=UNSET,

@@ -73,11 +73,13 @@ from .batch import (PEND_WINDOW, _CLIP, _LevelLoop, _beval_dpsub_chunk,
                     _beval_general_chunk, _beval_tree_chunk, _bfilter_chunk,
                     _lane_space)
 from .config import UNSET, OptimizerConfig, resolve_config
-from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap, _merge_best,
-                     _merge_scattered, _use_pallas, _use_pipeline)
+from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap, _fetch,
+                     _merge_best, _merge_scattered, _use_pallas,
+                     _use_pipeline)
 from .exec_cache import EXEC
 from .joingraph import JoinGraph, typed_edge_arrays
 from .plan import Counters, OptimizeResult, extract_plan
+from .telemetry import span
 from .shard import (BATCH_AXIS, _exec_key, _set_drop, _sharded, batch_mesh,
                     mesh_size)
 
@@ -141,47 +143,47 @@ class LatticeShardedEngine(_LevelLoop):
         self._exec_keys: set[tuple] = set()
         self._wall = 0.0
         self.counters = [Counters()]
-        self.timings: dict[str, float] = {}
-        D, nmax = self.D, self.nmax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        self._shard1 = NamedSharding(self.mesh, P(BATCH_AXIS))
-        bt = np.asarray(ur.binom_table(nmax))
-        self.binom_b = self._put(np.broadcast_to(bt, (D,) + bt.shape))
-        adj = np.zeros((1, nmax), np.int32)
-        for (u, v) in g.edges:
-            adj[0, u] |= 1 << v
-            adj[0, v] |= 1 << u
-        self.adj_b = self._put(np.broadcast_to(adj, (D, 1, nmax)))
-        self.emax = max(8, int(np.ceil(max(g.m, 1) / 8.0)) * 8)
-        # typed-join edge metadata, replicated (D, 1, emax) like emu/emv
-        self.typed = g.typed
-        if self.typed:
-            self._targs = tuple(
-                self._put(np.broadcast_to(a, (D, 1, self.emax)))
-                for a in typed_edge_arrays(g, self.emax))
-        else:
-            self._targs = ()
-        if algorithm == "mpdp_tree":
-            emu = np.zeros((1, self.emax), np.int32)
-            emv = np.zeros((1, self.emax), np.int32)
-            for ei, (u, v) in enumerate(g.edges):
-                emu[0, ei] = 1 << u
-                emv[0, ei] = 1 << v
-            self.emu_b = self._put(np.broadcast_to(emu, (D, 1, self.emax)))
-            self.emv_b = self._put(np.broadcast_to(emv, (D, 1, self.emax)))
-            self.m_b = self._put(np.full((D, 1), g.m, np.int32))
-        if algorithm == "mpdp_general":
-            # phase A is host-side and shared: one run per level feeds every
-            # device's pair windows (unlike core.shard, where each shard has
-            # its own queries and hence its own phase A)
-            eui = np.full(self.emax, -1, np.int32)
-            evi = np.full(self.emax, -1, np.int32)
-            eliv = np.zeros(self.emax, bool)
-            for ei, (u, v) in enumerate(g.edges):
-                eui[ei], evi[ei], eliv[ei] = u, v, True
-            self._phase_a_row = (jnp.asarray(adj[0]), jnp.asarray(eui),
-                                 jnp.asarray(evi), jnp.asarray(eliv))
-        self._init_memo()
+        with span("engine.setup"):
+            D, nmax = self.D, self.nmax
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            self._shard1 = NamedSharding(self.mesh, P(BATCH_AXIS))
+            bt = np.asarray(ur.binom_table(nmax))
+            self.binom_b = self._put(np.broadcast_to(bt, (D,) + bt.shape))
+            adj = np.zeros((1, nmax), np.int32)
+            for (u, v) in g.edges:
+                adj[0, u] |= 1 << v
+                adj[0, v] |= 1 << u
+            self.adj_b = self._put(np.broadcast_to(adj, (D, 1, nmax)))
+            self.emax = max(8, int(np.ceil(max(g.m, 1) / 8.0)) * 8)
+            # typed-join edge metadata, replicated (D, 1, emax) like emu/emv
+            self.typed = g.typed
+            if self.typed:
+                self._targs = tuple(
+                    self._put(np.broadcast_to(a, (D, 1, self.emax)))
+                    for a in typed_edge_arrays(g, self.emax))
+            else:
+                self._targs = ()
+            if algorithm == "mpdp_tree":
+                emu = np.zeros((1, self.emax), np.int32)
+                emv = np.zeros((1, self.emax), np.int32)
+                for ei, (u, v) in enumerate(g.edges):
+                    emu[0, ei] = 1 << u
+                    emv[0, ei] = 1 << v
+                self.emu_b = self._put(np.broadcast_to(emu, (D, 1, self.emax)))
+                self.emv_b = self._put(np.broadcast_to(emv, (D, 1, self.emax)))
+                self.m_b = self._put(np.full((D, 1), g.m, np.int32))
+            if algorithm == "mpdp_general":
+                # phase A is host-side and shared: one run per level feeds
+                # every device's pair windows (unlike core.shard, where each
+                # shard has its own queries and hence its own phase A)
+                eui = np.full(self.emax, -1, np.int32)
+                evi = np.full(self.emax, -1, np.int32)
+                eliv = np.zeros(self.emax, bool)
+                for ei, (u, v) in enumerate(g.edges):
+                    eui[ei], evi[ei], eliv[ei] = u, v, True
+                self._phase_a_row = (jnp.asarray(adj[0]), jnp.asarray(eui),
+                                     jnp.asarray(evi), jnp.asarray(eliv))
+            self._init_memo()
 
     # ----------------------------------------------------------- plumbing --
     def _put(self, x):
@@ -290,7 +292,6 @@ class LatticeShardedEngine(_LevelLoop):
         Device d's window starts at global rank ``roff[d]``, so
         ``foff = [-(roff[d] + c), roff[d+1] - roff[d] - c]`` makes the
         kernel decode global ranks and mask past the window's end."""
-        t0 = time.perf_counter()
         total = comb(self.g.n, i)
         roff = partition_lanes(total, self.D)
         steps_max = int(np.diff(roff).max())
@@ -307,14 +308,12 @@ class LatticeShardedEngine(_LevelLoop):
             faults.fire("chunk")
             self.chunks_dispatched += 1
             self._filter_drain(ctx, PEND_WINDOW)
-        self.timings["filter"] = (self.timings.get("filter", 0.0)
-                                  + time.perf_counter() - t0)
         return ctx
 
     def _filter_drain(self, ctx: dict, limit: int) -> None:
         pend, per_dev = ctx["pend"], ctx["per_dev"]
         while len(pend) > limit:
-            Sn, c, _ = jax.device_get(pend.popleft())
+            Sn, c, _ = _fetch(pend.popleft())
             for d in range(self.D):
                 if c[d].any():
                     per_dev[d].append(Sn[d][c[d]])
@@ -323,16 +322,12 @@ class LatticeShardedEngine(_LevelLoop):
         """Drain and concatenate survivors in device order — per-device rank
         windows are contiguous ascending, so this IS the global colex order
         the single-device filter produces."""
-        t0 = time.perf_counter()
         self._filter_drain(ctx, 0)
         parts = [a for d in range(self.D) for a in ctx["per_dev"][d]]
         sets = np.concatenate(parts) if parts else np.zeros(0, np.int32)
-        self.timings["filter"] = (self.timings.get("filter", 0.0)
-                                  + time.perf_counter() - t0)
         return sets
 
     def _register_level(self, i: int, sets_np: np.ndarray) -> None:
-        t0 = time.perf_counter()
         self._level_off[i] = self._next_off
         if len(sets_np):
             rows = cm.np_rows_for_sets(sets_np, self.g)
@@ -341,8 +336,6 @@ class LatticeShardedEngine(_LevelLoop):
                 self._next_off + np.arange(len(sets_np), dtype=np.int64),
                 sets_np)
             self._next_off += len(sets_np)
-        self.timings["filter"] = (self.timings.get("filter", 0.0)
-                                  + time.perf_counter() - t0)
 
     # ----------------------------------------------------------- evaluate --
     def _eval_dispatch(self, i: int, sets_np: np.ndarray):
@@ -352,7 +345,6 @@ class LatticeShardedEngine(_LevelLoop):
         ns = len(sets_np)
         if ns == 0:
             return None
-        t0 = time.perf_counter()
         D = self.D
         mult = self.g.m if self.algorithm == "mpdp_tree" else (1 << i)
         lane_off = partition_lanes(ns * mult, D)
@@ -394,15 +386,13 @@ class LatticeShardedEngine(_LevelLoop):
             faults.fire("chunk")
             self.chunks_dispatched += 1
             self._eval_drain(ctx, PEND_WINDOW)
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
         return ctx
 
     def _eval_drain(self, ctx: dict, limit: int) -> None:
         pend, sizes = ctx["pend"], ctx["sizes"]
         while len(pend) > limit:
             c0, seg0, out = pend.popleft()
-            scn, sln, evn, ccpn = jax.device_get(out)
+            scn, sln, evn, ccpn = _fetch(out)
             ctx["ev"] += evn
             ctx["ccp"] += ccpn
             for d in range(self.D):
@@ -413,19 +403,15 @@ class LatticeShardedEngine(_LevelLoop):
     def _eval_finalize(self, i: int, sets_np: np.ndarray, ctx) -> None:
         if ctx is None:
             return
-        t0 = time.perf_counter()
         self._eval_drain(ctx, 0)
         self.counters[0].evaluated += int(ctx["ev"].sum())
         self.counters[0].ccp += int(ctx["ccp"].sum())
         self._commit_level(sets_np, ctx["best_cost"], ctx["best_left"])
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
 
     # ------------------------------------------------- MPDP-general phase --
     def _pairs_level(self, sets_np: np.ndarray):
         """Phase A once on the host over the full level (shared by all
         devices — only the lane ranges differ per device)."""
-        t0 = time.perf_counter()
         if not len(sets_np):
             z = np.zeros(0, np.int32)
             return z, z, np.zeros(0, np.int64)
@@ -434,8 +420,6 @@ class LatticeShardedEngine(_LevelLoop):
                                       eliv_q, nmax=self.nmax, emax=self.emax,
                                       cyc_cap=self.cyc_cap)
         pk = np.searchsorted(sets_np, ps).astype(np.int64)
-        self.timings["blocks"] = (self.timings.get("blocks", 0.0)
-                                  + time.perf_counter() - t0)
         return ps, pb, pk
 
     def _eval_general_dispatch(self, i: int, sets_np: np.ndarray, pairs):
@@ -446,7 +430,6 @@ class LatticeShardedEngine(_LevelLoop):
         ps, pb, pk = pairs
         if not len(ps):
             return None
-        t0 = time.perf_counter()
         D = self.D
         sizes = bs.np_popcount(pb).astype(np.int64)
         offs = np.zeros(len(ps) + 1, np.int64)
@@ -498,15 +481,13 @@ class LatticeShardedEngine(_LevelLoop):
             faults.fire("chunk")
             self.chunks_dispatched += 1
             self._eval_general_drain(ctx, PEND_WINDOW)
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
         return ctx
 
     def _eval_general_drain(self, ctx: dict, limit: int) -> None:
         pend, pk = ctx["pend"], ctx["pk"]
         while len(pend) > limit:
             p0s, npairs, out = pend.popleft()
-            scn_all, sln_all, evn, ccpn = jax.device_get(out)
+            scn_all, sln_all, evn, ccpn = _fetch(out)
             ctx["ev"] += evn
             ctx["ccp"] += ccpn
             for d in range(self.D):
@@ -522,7 +503,6 @@ class LatticeShardedEngine(_LevelLoop):
     def _eval_general_finalize(self, i: int, sets_np: np.ndarray, ctx) -> None:
         if ctx is None:
             return
-        t0 = time.perf_counter()
         self._eval_general_drain(ctx, 0)
         ns = len(sets_np)
         best_cost = [np.full(ns, INF, np.float32) for _ in range(self.D)]
@@ -536,8 +516,6 @@ class LatticeShardedEngine(_LevelLoop):
         self.counters[0].evaluated += int(ctx["ev"].sum())
         self.counters[0].ccp += int(ctx["ccp"].sum())
         self._commit_level(sets_np, best_cost, best_left)
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
 
     # ------------------------------------------------------------- driver --
     # (run / run_levels / the pipelined rotation come from _LevelLoop)
@@ -546,8 +524,7 @@ class LatticeShardedEngine(_LevelLoop):
         ``tests/test_lattice_shard.py`` asserts it) and extract the plan."""
         t0 = time.perf_counter()
         g = self.g
-        cost_all = np.asarray(self.memo_cost)
-        left_all = np.asarray(self.memo_left)
+        cost_all, left_all = _fetch((self.memo_cost, self.memo_left))
         cost = float(cost_all[0, g.full_set])
         wall = self._wall + time.perf_counter() - t0
         if np.isfinite(cost):
@@ -567,7 +544,6 @@ class LatticeShardedEngine(_LevelLoop):
             r.info["degraded"] = {**self.degraded, **dinfo}
         else:
             raise RuntimeError("no plan found for lattice-sharded query")
-        r.timings = dict(self.timings)
         return [r]
 
     def memo_replicas(self) -> tuple[np.ndarray, np.ndarray]:

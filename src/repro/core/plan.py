@@ -72,7 +72,6 @@ class OptimizeResult:
     algorithm: str
     wall_s: float = 0.0
     levels: int = 0
-    timings: dict = dataclasses.field(default_factory=dict)
     # optional solver-specific explain payload (e.g. UnionDP records its
     # partition boundaries per recursion round and the re-optimization
     # loop's per-round total costs; see ``examples/query_service.py

@@ -60,6 +60,7 @@ import numpy as np
 
 from . import faults
 from . import telemetry as _telemetry
+from .telemetry import span
 from .batch import (PEND_WINDOW, BatchEngine, bucket_pending, dedup_pending,
                     lattice_pending, probe_stream, resolve_deferred)
 from .config import UNSET, OptimizerConfig, resolve_config
@@ -230,7 +231,8 @@ class StreamOptimizer:
         """Host-only flight finalize: fetch + extract + cache insert.  Runs
         while the *next* flight's trailing device work is still in flight."""
         t0 = time.perf_counter()
-        collected = eng.collect()
+        with span("engine.collect"):
+            collected = eng.collect()
         for qi, r in zip(fl.queries, collected):
             if getattr(eng, "redispatched", False):
                 r.info["redispatched"] = True
@@ -269,15 +271,17 @@ class StreamOptimizer:
         report = StreamReport(latency_s=[0.0] * len(graphs))
         results: list[OptimizeResult | None] = [None] * len(graphs)
         # same probe/dedup stages as optimize_many (shared helpers)
-        pending = probe_stream(graphs, results, self.cache, self.algorithm)
-        for qi, r in enumerate(results):
-            if r is not None:
-                report.latency_s[qi] = time.perf_counter() - t_stream
-                if r.algorithm.startswith("cache["):
-                    report.cache_hits += 1
-        pending, deferred, dup_rep = dedup_pending(graphs, pending,
-                                                   self.cache)
-        flights, solo = self.admit(graphs, pending)
+        with span("service.admit"):
+            pending = probe_stream(graphs, results, self.cache,
+                                   self.algorithm)
+            for qi, r in enumerate(results):
+                if r is not None:
+                    report.latency_s[qi] = time.perf_counter() - t_stream
+                    if r.algorithm.startswith("cache["):
+                        report.cache_hits += 1
+            pending, deferred, dup_rep = dedup_pending(graphs, pending,
+                                                       self.cache)
+            flights, solo = self.admit(graphs, pending)
         report.solo = len(solo)
 
         # double-buffered flight loop: finalize of flight i happens after
@@ -287,10 +291,12 @@ class StreamOptimizer:
             t_flight = time.perf_counter()
             eng = self._spawn(graphs, fl)
             if prev is not None:
-                self._finalize(graphs, *prev, t_stream, results, report)
+                with span("service.finalize"):
+                    self._finalize(graphs, *prev, t_stream, results, report)
             prev = (fl, eng, t_flight)
         if prev is not None:
-            self._finalize(graphs, *prev, t_stream, results, report)
+            with span("service.finalize"):
+                self._finalize(graphs, *prev, t_stream, results, report)
 
         for qi in solo:
             if self.config.deadline_s is None:

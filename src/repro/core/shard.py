@@ -82,11 +82,13 @@ from . import unrank as ur
 from .batch import (NMAX_BATCH, PEND_WINDOW, _CLIP, _LevelLoop, _bcap,
                     _beval_dpsub_chunk, _beval_general_chunk,
                     _beval_tree_chunk, _bfilter_chunk)
-from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap, _merge_best,
-                     _merge_scattered, _use_pallas, _use_pipeline)
+from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap, _fetch,
+                     _merge_best, _merge_scattered, _use_pallas,
+                     _use_pipeline)
 from .exec_cache import EXEC
 from .joingraph import JoinGraph, typed_edge_arrays
 from .plan import Counters, OptimizeResult, extract_plan
+from .telemetry import span
 
 BATCH_AXIS = "batch"
 
@@ -269,59 +271,61 @@ class ShardedBatchEngine(_LevelLoop):
         self.chunk = chunk
         self.size = 1 << self.nmax
         self.flat = self.bcap << self.nmax
-        self._shard1 = NamedSharding(self.mesh, P(BATCH_AXIS))
-        D, bcap, nmax = self.D, self.bcap, self.nmax
-        bt = np.asarray(ur.binom_table(nmax))
-        self.binom_b = self._put(np.broadcast_to(bt, (D,) + bt.shape))
-        adj = np.zeros((D, bcap, nmax), np.int32)
-        max_m = 1
-        for d, sh in enumerate(self.shard_graphs):
-            for q, g in enumerate(sh):
-                max_m = max(max_m, g.m)
-                for (u, v) in g.edges:
-                    adj[d, q, u] |= 1 << v
-                    adj[d, q, v] |= 1 << u
-        self.adj_b = self._put(adj)
-        self.emax = max(8, int(np.ceil(max_m / 8.0)) * 8)
-        emu = np.zeros((D, bcap, self.emax), np.int32)
-        emv = np.zeros((D, bcap, self.emax), np.int32)
-        eui = np.full((D, bcap, self.emax), -1, np.int32)
-        evi = np.full((D, bcap, self.emax), -1, np.int32)
-        eliv = np.zeros((D, bcap, self.emax), bool)
-        m_np = np.zeros((D, bcap), np.int32)
-        for d, sh in enumerate(self.shard_graphs):
-            for q, g in enumerate(sh):
-                m_np[d, q] = g.m
-                for ei, (u, v) in enumerate(g.edges):
-                    emu[d, q, ei] = 1 << u
-                    emv[d, q, ei] = 1 << v
-                    eui[d, q, ei], evi[d, q, ei], eliv[d, q, ei] = u, v, True
-        self.emu_b = self._put(emu)
-        self.emv_b = self._put(emv)
-        self.m_b = self._put(m_np)
-        # typed-join edge metadata, stacked (D, bcap, emax) like emu/emv;
-        # pad graphs are inner-only so their rows stay all-zero (mask-true)
-        self.typed = any(g.typed for g in self.graphs)
-        if self.typed:
-            tarr = [np.zeros((D, bcap, self.emax), np.int32)
-                    for _ in range(5)]
+        with span("engine.setup"):
+            self._shard1 = NamedSharding(self.mesh, P(BATCH_AXIS))
+            D, bcap, nmax = self.D, self.bcap, self.nmax
+            bt = np.asarray(ur.binom_table(nmax))
+            self.binom_b = self._put(np.broadcast_to(bt, (D,) + bt.shape))
+            adj = np.zeros((D, bcap, nmax), np.int32)
+            max_m = 1
             for d, sh in enumerate(self.shard_graphs):
                 for q, g in enumerate(sh):
-                    for a, col in zip(tarr, typed_edge_arrays(g, self.emax)):
-                        a[d, q] = col
-            self._targs = tuple(self._put(a) for a in tarr)
-        else:
-            self._targs = ()
-        if algorithm == "mpdp_general":
-            # phase A runs per (shard, query) on the host driver every
-            # level — build its per-query device rows once, not per level
-            self._phase_a_rows = [
-                [(jnp.asarray(adj[d, q]), jnp.asarray(eui[d, q]),
-                  jnp.asarray(evi[d, q]), jnp.asarray(eliv[d, q]))
-                 for q in range(self.Bs)] for d in range(D)]
-        self.counters = [Counters() for _ in self.graphs]
-        self.timings: dict[str, float] = {}
-        self._init_memo()
+                    max_m = max(max_m, g.m)
+                    for (u, v) in g.edges:
+                        adj[d, q, u] |= 1 << v
+                        adj[d, q, v] |= 1 << u
+            self.adj_b = self._put(adj)
+            self.emax = max(8, int(np.ceil(max_m / 8.0)) * 8)
+            emu = np.zeros((D, bcap, self.emax), np.int32)
+            emv = np.zeros((D, bcap, self.emax), np.int32)
+            eui = np.full((D, bcap, self.emax), -1, np.int32)
+            evi = np.full((D, bcap, self.emax), -1, np.int32)
+            eliv = np.zeros((D, bcap, self.emax), bool)
+            m_np = np.zeros((D, bcap), np.int32)
+            for d, sh in enumerate(self.shard_graphs):
+                for q, g in enumerate(sh):
+                    m_np[d, q] = g.m
+                    for ei, (u, v) in enumerate(g.edges):
+                        emu[d, q, ei] = 1 << u
+                        emv[d, q, ei] = 1 << v
+                        eui[d, q, ei], evi[d, q, ei] = u, v
+                        eliv[d, q, ei] = True
+            self.emu_b = self._put(emu)
+            self.emv_b = self._put(emv)
+            self.m_b = self._put(m_np)
+            # typed-join edge metadata, stacked (D, bcap, emax) like emu/emv;
+            # pad graphs are inner-only so their rows stay all-zero (mask-true)
+            self.typed = any(g.typed for g in self.graphs)
+            if self.typed:
+                tarr = [np.zeros((D, bcap, self.emax), np.int32)
+                        for _ in range(5)]
+                for d, sh in enumerate(self.shard_graphs):
+                    for q, g in enumerate(sh):
+                        cols = typed_edge_arrays(g, self.emax)
+                        for a, col in zip(tarr, cols):
+                            a[d, q] = col
+                self._targs = tuple(self._put(a) for a in tarr)
+            else:
+                self._targs = ()
+            if algorithm == "mpdp_general":
+                # phase A runs per (shard, query) on the host driver every
+                # level — build its per-query device rows once, not per level
+                self._phase_a_rows = [
+                    [(jnp.asarray(adj[d, q]), jnp.asarray(eui[d, q]),
+                      jnp.asarray(evi[d, q]), jnp.asarray(eliv[d, q]))
+                     for q in range(self.Bs)] for d in range(D)]
+            self.counters = [Counters() for _ in self.graphs]
+            self._init_memo()
 
     def _put(self, x):
         """Commit a stacked host array to the mesh, sharded over ``batch``."""
@@ -409,7 +413,6 @@ class ShardedBatchEngine(_LevelLoop):
         """Dispatch level i's fused filter chunks (all D shards per step);
         no host sync — ``_filter_collect`` fetches, so the pipelined driver
         can overlap the compaction with in-flight device evaluate."""
-        t0 = time.perf_counter()
         D, Bs, bcap = self.D, self.Bs, self.bcap
         totals = np.array([[comb(g.n, i) if g.n >= i else 0 for g in sh]
                            for sh in self.shard_graphs], np.int64)
@@ -430,8 +433,6 @@ class ShardedBatchEngine(_LevelLoop):
             faults.fire("chunk")
             self.chunks_dispatched += 1
             self._filter_drain(ctx, self.pend_window)
-        self.timings["filter"] = (self.timings.get("filter", 0.0)
-                                  + time.perf_counter() - t0)
         return ctx
 
     def _filter_drain(self, ctx: dict, limit: int) -> None:
@@ -439,7 +440,7 @@ class ShardedBatchEngine(_LevelLoop):
         fused ``device_get`` per chunk covers all D shards)."""
         pend, per_q = ctx["pend"], ctx["per_q"]
         while len(pend) > limit:
-            Sn, c, qn = jax.device_get(pend.popleft())
+            Sn, c, qn = _fetch(pend.popleft())
             for d in range(self.D):
                 if c[d].any():
                     Sc = Sn[d][c[d]]
@@ -450,18 +451,14 @@ class ShardedBatchEngine(_LevelLoop):
     def _filter_collect(self, ctx: dict) -> list[list[np.ndarray]]:
         """Drain the remaining filter chunks and build the per-shard
         per-query set lists."""
-        t0 = time.perf_counter()
         self._filter_drain(ctx, 0)
         sets = [[np.concatenate(l) if l else np.zeros(0, np.int32)
                  for l in ctx["per_q"][d]] for d in range(self.D)]
-        self.timings["filter"] = (self.timings.get("filter", 0.0)
-                                  + time.perf_counter() - t0)
         return sets
 
     def _register_level(self, i: int, sets) -> None:
         """Host rows (shared ``cost.np_rows_for_sets``) + registration, per
         shard per query — identical to ``BatchEngine._register_level``."""
-        t0 = time.perf_counter()
         idx_d, rows_d, pos_d, set_d = [], [], [], []
         z64, z32 = np.zeros(0, np.int64), np.zeros(0, np.int32)
         zf = np.zeros(0, np.float32)
@@ -486,8 +483,6 @@ class ShardedBatchEngine(_LevelLoop):
         if any(len(x) for x in idx_d):
             self._scatter(idx_d, rows=rows_d)
             self._set_all_sets(pos_d, set_d)
-        self.timings["filter"] = (self.timings.get("filter", 0.0)
-                                  + time.perf_counter() - t0)
 
     # ---------------------------------------------------------- evaluate ---
     def _bump_counters(self, ev_acc, ccp_acc) -> None:
@@ -543,7 +538,6 @@ class ShardedBatchEngine(_LevelLoop):
         total_max = int(totals.max())
         if total_max == 0:
             return None
-        t0 = time.perf_counter()
         soff = np.zeros((D, Bs + 1), np.int64)
         np.cumsum(ns, axis=1, out=soff[:, 1:])
         loff = np.zeros((D, bcap), np.int64)
@@ -595,8 +589,6 @@ class ShardedBatchEngine(_LevelLoop):
             faults.fire("chunk")
             self.chunks_dispatched += 1
             self._eval_drain(ctx, self.pend_window)
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
         return ctx
 
     def _eval_drain(self, ctx: dict, limit: int) -> None:
@@ -606,7 +598,7 @@ class ShardedBatchEngine(_LevelLoop):
         pend = ctx["pend"]
         while len(pend) > limit:
             lane0, seg0, out = pend.popleft()
-            scn, sln, evn, ccpn = jax.device_get(out)
+            scn, sln, evn, ccpn = _fetch(out)
             ctx["ev"] += evn[:, :Bs]
             ctx["ccp"] += ccpn[:, :Bs]
             for d in range(self.D):
@@ -617,19 +609,15 @@ class ShardedBatchEngine(_LevelLoop):
     def _eval_finalize(self, i: int, sets, ctx) -> None:
         if ctx is None:
             return
-        t0 = time.perf_counter()
         self._eval_drain(ctx, 0)
         self._bump_counters(ctx["ev"], ctx["ccp"])
         self._commit_best(sets, ctx["best_cost"], ctx["best_left"])
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
 
     # ------------------------------------------------- MPDP-general phase --
     def _pairs_level(self, sets):
         """Phase A per shard per query (shared ``blocks.np_pairs_for_sets``
         host driver), fused into per-shard (set, block, qid, segment) pair
         arrays — the per-shard analogue of ``BatchEngine._pairs_level``."""
-        t0 = time.perf_counter()
         out = []
         for d in range(self.D):
             soff = 0
@@ -653,8 +641,6 @@ class ShardedBatchEngine(_LevelLoop):
             else:
                 z = np.zeros(0, np.int32)
                 out.append((z, z, z, np.zeros(0, np.int64)))
-        self.timings["blocks"] = (self.timings.get("blocks", 0.0)
-                                  + time.perf_counter() - t0)
         return out
 
     def _eval_general_dispatch(self, i: int, sets, pairs):
@@ -663,7 +649,6 @@ class ShardedBatchEngine(_LevelLoop):
         D = self.D
         if not any(len(p[0]) for p in pairs):
             return None
-        t0 = time.perf_counter()
         offs_by_d, totals = [], np.zeros(D, np.int64)
         for d, (ps, pb, _, _) in enumerate(pairs):
             sizes = bs.np_popcount(pb).astype(np.int64)
@@ -717,8 +702,6 @@ class ShardedBatchEngine(_LevelLoop):
             faults.fire("chunk")
             self.chunks_dispatched += 1
             self._eval_general_drain(ctx, self.pend_window)
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
         return ctx
 
     def _eval_general_drain(self, ctx: dict, limit: int) -> None:
@@ -728,7 +711,7 @@ class ShardedBatchEngine(_LevelLoop):
         pend = ctx["pend"]
         while len(pend) > limit:
             p0s, npairs, out = pend.popleft()
-            scn_all, sln_all, evn, ccpn = jax.device_get(out)
+            scn_all, sln_all, evn, ccpn = _fetch(out)
             ctx["ev"] += evn[:, :Bs]
             ctx["ccp"] += ccpn[:, :Bs]
             for d in range(self.D):
@@ -744,7 +727,6 @@ class ShardedBatchEngine(_LevelLoop):
     def _eval_general_finalize(self, i: int, sets, ctx) -> None:
         if ctx is None:
             return
-        t0 = time.perf_counter()
         D = self.D
         self._eval_general_drain(ctx, 0)
         best_cost = [np.full(sum(len(s) for s in sets[d]), INF, np.float32)
@@ -759,8 +741,6 @@ class ShardedBatchEngine(_LevelLoop):
                                  np.concatenate(ctx["c"][d]),
                                  np.concatenate(ctx["l"][d]))
         self._commit_best(sets, best_cost, best_left)
-        self.timings["evaluate"] = (self.timings.get("evaluate", 0.0)
-                                    + time.perf_counter() - t0)
 
     # ------------------------------------------------------------ driver ---
     # (run / run_levels / the pipelined rotation come from _LevelLoop)
@@ -768,8 +748,7 @@ class ShardedBatchEngine(_LevelLoop):
         """Fetch the stacked memo and extract per-query results (see
         ``BatchEngine.collect``)."""
         t0 = time.perf_counter()
-        cost_all = np.asarray(self.memo_cost)
-        left_all = np.asarray(self.memo_left)
+        cost_all, left_all = _fetch((self.memo_cost, self.memo_left))
         out = []
         wall = self._wall + time.perf_counter() - t0
         for qi, g in enumerate(self.graphs):
@@ -798,6 +777,5 @@ class ShardedBatchEngine(_LevelLoop):
                 r.info["degraded"] = {**self.degraded, **dinfo}
             else:
                 raise RuntimeError(f"no plan found for batch query {qi}")
-            r.timings = dict(self.timings)
             out.append(r)
         return out

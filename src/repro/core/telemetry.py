@@ -16,10 +16,24 @@ Records feed :class:`repro.core.policy.PolicyTable`, which EMA-learns
 per-(NMAX bucket, lane space) execution profiles, and the daemon's
 STATS reply, which aggregates them across requests.  See
 ``docs/telemetry.md`` for the schema and the bench gates built on it.
+
+:func:`span` marks where the host time goes: a named span on the JAX
+profiler's host timeline, the clock the device events share.  The names
+are ``<layer>.<step>`` (``daemon.job``, ``service.admit``,
+``level.fetch``, ``uniondp.reopt``; the list is in ``docs/telemetry.md``).
 """
 from __future__ import annotations
 
 import dataclasses
+
+from jax.profiler import TraceAnnotation
+
+
+def span(name: str, **meta) -> TraceAnnotation:
+    """Context manager: one host span named ``name`` with ``meta`` as its
+    arguments, kept by the profiler when a trace runs (``jax.profiler``).
+    With no trace running it costs one object and a flag test."""
+    return TraceAnnotation(name, **meta)
 
 
 @dataclasses.dataclass

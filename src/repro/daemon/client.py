@@ -172,6 +172,13 @@ class DaemonClient:
         """Ask the daemon to shut down gracefully (drain + checkpoint)."""
         self._call({"op": "drain"})
 
+    def trace(self, directory: str, seconds: float) -> str:
+        """Have the daemon profile itself for ``seconds`` into
+        ``directory`` (a path on the daemon's host); returns it once the
+        trace is written."""
+        return self._call({"op": "trace", "dir": directory,
+                           "seconds": seconds})["dir"]
+
     def close(self) -> None:
         try:
             self._sock.close()

@@ -25,6 +25,8 @@ server's engines, so daemon results compare bit-identical to in-process
   stats      {"op": "stats"}
   ping       {"op": "ping"}
   drain      {"op": "drain"}        # graceful shutdown request
+  trace      {"op": "trace", "dir": str, "seconds": float}
+                                    # profile the daemon into dir
 
 **Responses**: ``{"ok": true, ...}`` on success; ``{"ok": false,
 "shed": true, "reason": ...}`` when admission control rejects (queue or

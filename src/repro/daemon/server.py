@@ -50,18 +50,21 @@ from collections import deque
 
 from . import protocol as proto
 from ..core import faults
+from ..core.telemetry import span
 
 
 class _Job:
-    """One admitted optimize request: raw message in, encoded reply out."""
+    """One admitted optimize request: raw message in, encoded reply out.
+    ``admitted`` is the ``perf_counter`` time it entered the queue."""
 
-    __slots__ = ("msg", "tenant", "done", "reply")
+    __slots__ = ("msg", "tenant", "done", "reply", "admitted")
 
     def __init__(self, msg: dict, tenant: str):
         self.msg = msg
         self.tenant = tenant
         self.done = threading.Event()
         self.reply: dict | None = None
+        self.admitted = 0.0
 
 
 class OptimizerDaemon:
@@ -142,6 +145,9 @@ class OptimizerDaemon:
         self._flights = 0
         self._since_checkpoint = 0
         self._checkpoints = 0
+        self._jobs_started = 0                     # worker-only
+        self._queue_wait_s = 0.0
+        self._queue_waits: deque[float] = deque(maxlen=history)
         self._request_walls: deque[float] = deque(maxlen=history)
         self._flight_walls: deque[float] = deque(maxlen=history)
         # flight-telemetry roll-up (telemetry.aggregate shape, summed
@@ -304,7 +310,25 @@ class OptimizerDaemon:
             return {"ok": True, "draining": True}
         if op == "optimize":
             return self._optimize_request(msg)
+        if op == "trace":
+            return self._trace(str(msg["dir"]), float(msg["seconds"]))
         return {"ok": False, "error": f"unknown op {op!r}"}
+
+    @staticmethod
+    def _trace(path: str, seconds: float) -> dict:
+        """Profile the daemon process for ``seconds`` into ``path`` with
+        ``jax.profiler``: the device's executables beside the program's
+        spans (``docs/telemetry.md``).  Only this connection waits; a
+        trace already running fails the request."""
+        import jax
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(path, profiler_options=opts)
+        try:
+            time.sleep(seconds)
+        finally:
+            jax.profiler.stop_trace()
+        return {"ok": True, "dir": path}
 
     def _optimize_request(self, msg: dict) -> dict:
         tenant = str(msg.get("tenant", "default"))
@@ -318,6 +342,7 @@ class OptimizerDaemon:
                         "tenant": tenant}
             self._tenant_inflight[tenant] = \
                 self._tenant_inflight.get(tenant, 0) + 1
+        job.admitted = time.perf_counter()
         try:
             self._queue.put_nowait(job)
         except queue.Full:
@@ -358,8 +383,13 @@ class OptimizerDaemon:
                     job.done.set()
 
     def _worker_loop(self) -> None:
+        """Spans: ``daemon.idle`` while the worker waits for a job, then
+        ``daemon.job`` (its sequence number and tenant as arguments) from
+        pickup until the reply is encoded.  Pickup adds the job's wait since
+        admission to the ``queue_wait_s`` counter."""
         while True:
-            job = self._queue.get()
+            with span("daemon.idle"):
+                job = self._queue.get()
             if job is None:
                 return
             with self._lock:
@@ -368,8 +398,14 @@ class OptimizerDaemon:
             if self._worker_gate is not None:      # to _worker_main
                 self._worker_gate.wait()
             t0 = time.perf_counter()
+            self._jobs_started += 1
+            with self._lock:
+                self._queue_wait_s += t0 - job.admitted
+                self._queue_waits.append(t0 - job.admitted)
             try:
-                job.reply = self._run_job(job, t0)
+                with span("daemon.job", seq=self._jobs_started,
+                          tenant=job.tenant):
+                    job.reply = self._run_job(job, t0)
             except Exception as e:
                 with self._lock:
                     self._errors += 1
@@ -382,10 +418,15 @@ class OptimizerDaemon:
                 job.done.set()
 
     def _run_job(self, job: _Job, t0: float) -> dict:
+        """Decode, optimize, encode.  The reply's ``wall_s`` runs from
+        pickup (``t0``) to the results; STATS ``request_wall_s`` from
+        admission to the encoded reply."""
         from ..core.config import OptimizerConfig
         from ..core.service import StreamOptimizer
-        cfg = OptimizerConfig.from_wire(job.msg.get("config") or {})
-        graphs = [proto.graph_from_wire(d) for d in job.msg.get("graphs", [])]
+        with span("daemon.decode"):
+            cfg = OptimizerConfig.from_wire(job.msg.get("config") or {})
+            graphs = [proto.graph_from_wire(d)
+                      for d in job.msg.get("graphs", [])]
         # substitute the daemon-owned shared state; a request that pins
         # devices= keeps its pin, otherwise the daemon's default mesh rules
         cfg = cfg.replace(
@@ -396,12 +437,22 @@ class OptimizerDaemon:
         hits0 = self.cache.stats.hits
         results, report = StreamOptimizer(config=cfg).optimize_stream(graphs)
         wall = time.perf_counter() - t0
+        with span("daemon.encode"):
+            reply = {"ok": True,
+                     "results": [proto.result_to_wire(r) for r in results],
+                     "wall_s": wall,
+                     "flights": len(report.flights),
+                     "lattice": report.lattice,
+                     "solo": report.solo,
+                     "cache_hits": self.cache.stats.hits - hits0,
+                     "degraded": sum(1 for r in results
+                                     if "degraded" in r.info)}
         tele = report.telemetry_summary()
         with self._lock:
             self._requests += 1
             self._queries += len(graphs)
             self._flights += len(report.flights)
-            self._request_walls.append(wall)
+            self._request_walls.append(time.perf_counter() - job.admitted)
             self._flight_walls.extend(f.wall_s for f in report.flights)
             for k in self._telemetry:
                 self._telemetry[k] += int(tele.get(k, 0))
@@ -411,14 +462,7 @@ class OptimizerDaemon:
             tt["queries"] += len(graphs)
             self._since_checkpoint += 1
         self._checkpoint()
-        return {"ok": True,
-                "results": [proto.result_to_wire(r) for r in results],
-                "wall_s": wall,
-                "flights": len(report.flights),
-                "lattice": report.lattice,
-                "solo": report.solo,
-                "cache_hits": self.cache.stats.hits - hits0,
-                "degraded": sum(1 for r in results if "degraded" in r.info)}
+        return reply
 
     def _checkpoint(self, force: bool = False) -> None:
         """Atomic cache + policy checkpoint (worker/drain only — both
@@ -464,6 +508,7 @@ class OptimizerDaemon:
                 "tenants": {t: dict(v)
                             for t, v in sorted(self._tenant_totals.items())},
                 "checkpoints": self._checkpoints,
+                "queue_wait_s": self._percentiles(self._queue_waits),
                 "request_wall_s": self._percentiles(self._request_walls),
                 "flight_wall_s": self._percentiles(self._flight_walls),
                 "plancache": {
@@ -473,7 +518,8 @@ class OptimizerDaemon:
                     "inserts": self.cache.stats.inserts,
                     "evictions": self.cache.stats.evictions,
                 },
-                "telemetry": dict(self._telemetry),
+                "telemetry": dict(self._telemetry,
+                                  queue_wait_s=self._queue_wait_s),
             }
             if self.policy is not None:
                 out["policy"] = self.policy.summary()

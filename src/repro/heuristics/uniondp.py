@@ -60,6 +60,7 @@ import numpy as np
 from ..core import cost as cm
 from ..core.joingraph import JoinGraph
 from ..core.plan import Counters, OptimizeResult, cost_plan
+from ..core.telemetry import span
 from .common import UnitGraph, expand_unit_plan
 
 
@@ -214,14 +215,15 @@ def _reoptimize(g: JoinGraph, plan, k: int, batch_sub, batch: int,
     best = plan
     costs = [best.cost]
     for _ in range(max_rounds):
-        ug = UnitGraph(g)
-        plan_tree = _idp.tree_from_plan(best)
-        goo_tree = _idp._goo_tree(ug)
-        _idp._recost(plan_tree, ug)
-        _idp._recost(goo_tree, ug)
-        tree = plan_tree if plan_tree.cost <= goo_tree.cost else goo_tree
-        unit = _idp.run_rounds(ug, tree, k, batch, batch_sub)
-        cand = cost_plan(unit.plan, g)
+        with span("uniondp.reopt"):
+            ug = UnitGraph(g)
+            plan_tree = _idp.tree_from_plan(best)
+            goo_tree = _idp._goo_tree(ug)
+            _idp._recost(plan_tree, ug)
+            _idp._recost(goo_tree, ug)
+            tree = plan_tree if plan_tree.cost <= goo_tree.cost else goo_tree
+            unit = _idp.run_rounds(ug, tree, k, batch, batch_sub)
+            cand = cost_plan(unit.plan, g)
         if not cand.cost < best.cost:
             break
         best = cand
@@ -234,6 +236,16 @@ def solve(g: JoinGraph, k: int = 15, subsolver: str = "mpdp",
           reopt_rounds: int = 4, reopt_batch: int = 4,
           devices=None, mesh=None,
           pipeline: bool | None = None, policy=None) -> OptimizeResult:
+    """UnionDP over ``g`` (module doc), under a ``uniondp.solve`` span with
+    one ``uniondp.partition`` span per partition round and one
+    ``uniondp.reopt`` span per re-optimization pass inside it."""
+    with span("uniondp.solve"):
+        return _solve(g, k, subsolver, goo_floor, partition, reopt_rounds,
+                      reopt_batch, devices, mesh, pipeline, policy)
+
+
+def _solve(g, k, subsolver, goo_floor, partition, reopt_rounds, reopt_batch,
+           devices, mesh, pipeline, policy) -> OptimizeResult:
     t0 = time.perf_counter()
     counters = Counters()
     if g.typed:
@@ -278,27 +290,30 @@ def solve(g: JoinGraph, k: int = 15, subsolver: str = "mpdp",
     info: dict = {"partitions": [], "round_costs": []}
     ug = UnitGraph(g)
     while ug.n > k:
-        groups = _partition(ug, k, rule=partition)
-        if all(len(gr) == 1 for gr in groups):
-            # cannot union anything (all merges would exceed k): force the
-            # two cheapest-connected groups together to guarantee progress
-            a, b = ug.edges[0]
-            groups = [[a, b]] + [[i] for i in range(ug.n) if i not in (a, b)]
-        info["partitions"].append(
-            [ug.rel_ids(sorted(gr)) for gr in groups])
-        # capture unit objects up-front: each merge reindexes ug.units.
-        # Partitions are disjoint, so every subgraph can be extracted from
-        # the pre-merge snapshot and the whole round batched.
-        jobs = []
-        for gr in groups:
-            if len(gr) < 2:
-                continue
-            jg, idxs = ug.as_joingraph(sorted(gr))   # pre-merge: ids == gr
-            jobs.append((jg, [ug.units[i] for i in idxs]))
-        plans = batch_solve([jg for jg, _ in jobs])
-        for (jg, ulist), plan in zip(jobs, plans):
-            ids = sorted(ug.index_of(t) for t in ulist)
-            ug.merge(ids, expand_unit_plan(plan, ulist, g))
+        with span("uniondp.partition"):
+            groups = _partition(ug, k, rule=partition)
+            if all(len(gr) == 1 for gr in groups):
+                # cannot union anything (all merges would exceed k): force
+                # the two cheapest-connected groups together to guarantee
+                # progress
+                a, b = ug.edges[0]
+                groups = [[a, b]] + [[i] for i in range(ug.n)
+                                     if i not in (a, b)]
+            info["partitions"].append(
+                [ug.rel_ids(sorted(gr)) for gr in groups])
+            # capture unit objects up-front: each merge reindexes ug.units.
+            # Partitions are disjoint, so every subgraph can be extracted
+            # from the pre-merge snapshot and the whole round batched.
+            jobs = []
+            for gr in groups:
+                if len(gr) < 2:
+                    continue
+                jg, idxs = ug.as_joingraph(sorted(gr))   # pre-merge: ids == gr
+                jobs.append((jg, [ug.units[i] for i in idxs]))
+            plans = batch_solve([jg for jg, _ in jobs])
+            for (jg, ulist), plan in zip(jobs, plans):
+                ids = sorted(ug.index_of(t) for t in ulist)
+                ug.merge(ids, expand_unit_plan(plan, ulist, g))
     jg, idxs = ug.as_joingraph()
     p = expand_unit_plan(batch_solve([jg])[0], [ug.units[i] for i in idxs], g)
     p = cost_plan(p, g)

@@ -161,6 +161,48 @@ class TestDaemonEndToEnd:
                 raise DaemonError(reply["error"])
 
 
+    def test_queue_wait_and_request_wall_cover_a_held_job(self, tmp_path):
+        """A job held before pickup: STATS ``queue_wait_s`` and
+        ``request_wall_s`` (admission to encoded reply) cover the hold, the
+        reply's ``wall_s`` (pickup to results) does not."""
+        hold = 0.3
+        gate = threading.Event()
+        d = OptimizerDaemon(socket_path=str(tmp_path / "qw.sock"),
+                            worker_gate=gate)
+        d.start()
+        meta = {}
+
+        def send():
+            with DaemonClient(socket_path=d.address, tenant="h") as c:
+                c.optimize(SMALL[:1])
+                meta.update(c.last_meta)
+
+        try:
+            t = threading.Thread(target=send)
+            t.start()
+            deadline = time.monotonic() + 10
+            while time.monotonic() < deadline:
+                with d._lock:
+                    if d._tenant_inflight.get("h") == 1 and d._queue.empty():
+                        break
+                time.sleep(0.005)
+            else:
+                pytest.fail("worker never picked up the job")
+            time.sleep(hold)
+            gate.set()
+            t.join(timeout=60)
+            with DaemonClient(socket_path=d.address) as c:
+                st = c.stats()
+        finally:
+            gate.set()
+            d.drain()
+        waited = st["telemetry"]["queue_wait_s"]
+        assert waited >= hold
+        assert st["queue_wait_s"]["p50"] == pytest.approx(waited)
+        assert st["request_wall_s"]["p50"] >= waited + meta["wall_s"]
+        assert meta["wall_s"] < st["request_wall_s"]["p50"] - hold
+
+
 # ============================================================= backpressure
 
 class TestBackpressure:
