@@ -80,14 +80,14 @@ from . import faults
 from . import unrank as ur
 from .config import (MAX_FLIGHT, UNSET, OptimizerConfig, alias_kwarg,
                      resolve_config)
-from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap, _fetch,
+from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap,
                      _merge_best, _merge_scattered, _prune, _scatter_f32,
                      _scatter_i32, _typed_lane_cost, _use_pallas,
                      _use_pipeline)
 from .exec_cache import EXEC
 from .joingraph import JoinGraph, typed_edge_arrays
 from .plan import Counters, OptimizeResult, extract_plan, leaf_plan
-from .telemetry import span
+from .telemetry import fetch, span
 
 NMAX_BATCH = 16          # memo is (bcap << NMAX): past 16 fall back to solo
 MAX_BATCH = MAX_FLIGHT   # sub-batch cap: bounds memo memory + recompiles
@@ -483,6 +483,8 @@ class BatchEngine(_LevelLoop):
         self._deadline_at: float | None = None
         self.degraded: dict | None = None
         self.chunks_dispatched = 0
+        self.blocks_sets = 0               # phase A: sets / launched slots
+        self.blocks_slots = 0
         self._exec_keys: set[tuple] = set()
         self._wall = 0.0
         self.B = len(graphs)
@@ -644,9 +646,9 @@ class BatchEngine(_LevelLoop):
         pend, per_q = ctx["pend"], ctx["per_q"]
         while len(pend) > limit:
             S, conn, qid = pend.popleft()
-            c = _fetch(conn)
+            c = fetch(conn)
             if c.any():
-                S, qid = _fetch((S, qid))
+                S, qid = fetch((S, qid))
                 Sc = S[c]
                 qc = qid[c]
                 for q in np.unique(qc):
@@ -767,7 +769,7 @@ class BatchEngine(_LevelLoop):
         pend = ctx["pend"]
         while len(pend) > limit:
             seg0, out = pend.popleft()
-            sc, sl, ev_q, ccp_q = _fetch(out)
+            sc, sl, ev_q, ccp_q = fetch(out)
             ctx["ev"] += ev_q[: self.B]
             ctx["ccp"] += ccp_q[: self.B]
             _merge_best(ctx["best_cost"], ctx["best_left"], seg0, sc, sl)
@@ -792,10 +794,12 @@ class BatchEngine(_LevelLoop):
         for q, sets_q in enumerate(sets_by_q):
             if not len(sets_q):
                 continue
-            ps_q, pb_q = bl.np_pairs_for_sets(
+            ps_q, pb_q, slots = bl.np_pairs_for_sets(
                 sets_q, self.graphs[q], self.adj_b[q], self.eu_idx_b[q],
                 self.ev_idx_b[q], self.edge_live_b[q],
                 nmax=self.nmax, emax=self.emax, cyc_cap=self.cyc_cap)
+            self.blocks_sets += len(sets_q)
+            self.blocks_slots += slots
             ps_l.append(ps_q)
             pb_l.append(pb_q)
             pq_l.append(np.full(len(ps_q), q, np.int32))
@@ -860,7 +864,7 @@ class BatchEngine(_LevelLoop):
         pend, pk = ctx["pend"], ctx["pk"]
         while len(pend) > limit:
             p0, npair, out = pend.popleft()
-            sc, sl, ev_q, ccp_q = _fetch(out)
+            sc, sl, ev_q, ccp_q = fetch(out)
             ctx["ev"] += ev_q[: self.B]
             ctx["ccp"] += ccp_q[: self.B]
             scn = sc[:npair]
@@ -891,7 +895,7 @@ class BatchEngine(_LevelLoop):
         the streaming service this host-only finalize is deferred so it
         overlaps the next flight's device work."""
         t0 = time.perf_counter()
-        cost_all, left_all = _fetch((self.memo_cost, self.memo_left))
+        cost_all, left_all = fetch((self.memo_cost, self.memo_left))
         out = []
         wall = self._wall + time.perf_counter() - t0
         for q, g in enumerate(self.graphs):

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from . import bitset as bs
+from .telemetry import fetch
 
 
 # ------------------------------------------------------------------ oracle --
@@ -232,6 +233,23 @@ def has_cut_vertex_batch(S, adj, nmax: int):
 # space: chunked device block finding + host compaction into sorted
 # (set, block) pair arrays.
 
+SCAP = 4096       # sets per full phase-A launch
+SCAP_MIN = 256    # fewest set slots of a launch (bounds the compile keys)
+
+
+def _launches(sets_np):
+    """Phase A's launches over a level: ``(s0, sets, padded)`` per launch.
+    Full launches hold ``SCAP`` sets; the last (or only) one is sized to
+    the next power of two at or above its set count, at least
+    ``SCAP_MIN``, so a small level does not pay for 4096 slots."""
+    for s0 in range(0, len(sets_np), SCAP):
+        sl = sets_np[s0: s0 + SCAP]
+        scap = max(SCAP_MIN, 1 << (len(sl) - 1).bit_length())
+        pad = np.zeros(scap, np.int32)
+        pad[: len(sl)] = sl
+        yield s0, sl, pad
+
+
 @partial(jax.jit, static_argnames=("nmax", "emax", "cyc_cap", "scap"))
 def blocks_chunk(sets_pad, n_valid, adj, eu_idx, ev_idx, edge_live,
                  *, nmax: int, emax: int, cyc_cap: int, scap: int):
@@ -273,7 +291,9 @@ def blocks_chunk(sets_pad, n_valid, adj, eu_idx, ev_idx, edge_live,
 
 def np_pairs_for_sets(sets_np, g, adj, eu_idx, ev_idx, edge_live,
                       *, nmax: int, emax: int, cyc_cap: int):
-    """Phase A host driver: compacted (set, block) pair arrays for a level.
+    """Phase A on the host: compacted (set, block) pair arrays for a level,
+    and the set slots its launches held (``_launches``; the engines tally
+    them as ``blocks_slots`` beside ``blocks_sets`` += ``len(sets_np)``).
 
     ``adj``/``eu_idx``/``ev_idx``/``edge_live`` are the device-side arrays of
     the query (one query at a time — BatchEngine loops its sub-batch here,
@@ -282,23 +302,20 @@ def np_pairs_for_sets(sets_np, g, adj, eu_idx, ev_idx, edge_live,
     """
     mu = g.m - g.n + 1
     pair_set, pair_block = [], []
+    slots = 0
     if mu <= cyc_cap:
-        scap = 4096
         # cyclomatic number of any induced subgraph <= mu(G): size the
         # static fundamental-cycle slots to the query, not the ceiling
         # (perf log: 24 -> mu slots cut phase A ~4x on near-tree graphs)
         eff_cap = max(1, min(cyc_cap, mu))
-        for s0 in range(0, len(sets_np), scap):
-            sl = sets_np[s0: s0 + scap]
-            pad = np.zeros(scap, np.int32)
-            pad[: len(sl)] = sl
+        for _, sl, pad in _launches(sets_np):
+            slots += len(pad)
             merged, bridge = blocks_chunk(
                 jnp.asarray(pad), jnp.int32(len(sl)), adj,
                 eu_idx, ev_idx, edge_live,
-                nmax=nmax, emax=emax, cyc_cap=eff_cap, scap=scap)
-            mg = np.asarray(merged)[: len(sl)]
-            br = np.asarray(bridge)[: len(sl)]
-            both = np.concatenate([mg, br], axis=1)
+                nmax=nmax, emax=emax, cyc_cap=eff_cap, scap=len(pad))
+            mg, br = fetch((merged, bridge))
+            both = np.concatenate([mg[: len(sl)], br[: len(sl)]], axis=1)
             snp = np.repeat(sl[:, None], both.shape[1], axis=1)
             nz = both != 0
             pair_set.append(snp[nz])
@@ -306,14 +323,11 @@ def np_pairs_for_sets(sets_np, g, adj, eu_idx, ev_idx, edge_live,
     else:
         # dense path: no-cut-vertex sets are single blocks (cliques);
         # rare cut-vertex sets fall back to the host oracle
-        scap = 4096
         flags = np.zeros(len(sets_np), bool)
-        for s0 in range(0, len(sets_np), scap):
-            sl = sets_np[s0: s0 + scap]
-            pad = np.zeros(scap, np.int32)
-            pad[: len(sl)] = sl
+        for s0, sl, pad in _launches(sets_np):
+            slots += len(pad)
             hc = has_cut_vertex_batch(jnp.asarray(pad), adj, nmax)
-            flags[s0: s0 + len(sl)] = np.asarray(hc)[: len(sl)]
+            flags[s0: s0 + len(sl)] = fetch(hc)[: len(sl)]
         easy = sets_np[~flags]
         pair_set.append(easy)
         pair_block.append(easy)
@@ -325,4 +339,4 @@ def np_pairs_for_sets(sets_np, g, adj, eu_idx, ev_idx, edge_live,
     pb = np.concatenate(pair_block) if pair_block else np.zeros(0, np.int32)
     # order pairs by set (stable) so lane segments stay contiguous
     order = np.argsort(ps, kind="stable")
-    return ps[order], pb[order]
+    return ps[order], pb[order], slots

@@ -42,7 +42,7 @@ from .config import (CHUNK, CYC_CAP_DEFAULT, UNSET, OptimizerConfig,
                      alias_kwarg, resolve_config)
 from .joingraph import DeviceGraph, JoinGraph
 from .plan import Counters, OptimizeResult, extract_plan
-from .telemetry import span
+from .telemetry import fetch, span
 
 INF = np.float32(np.inf)
 
@@ -62,14 +62,6 @@ def _use_pipeline() -> bool:
     bit-identical to the synchronous default — only dispatch order changes."""
     import os
     return os.environ.get("REPRO_PIPELINE", "0") == "1"
-
-
-def _fetch(tree):
-    """Blocking device-to-host copy of ``tree`` (an array or a tuple of
-    them) under a ``level.fetch`` span: one host round trip of a level
-    loop."""
-    with span("level.fetch"):
-        return jax.device_get(tree)
 
 
 def _cap(n: int, lo: int = 1024) -> int:
@@ -436,9 +428,9 @@ class ExactEngine:
             S, conn = _filter_chunk(
                 jnp.int32(rank0), jnp.int32(total), jnp.int32(i), self.binom,
                 self.dg.adj, nmax=self.nmax, chunk=self.chunk)
-            c = _fetch(conn)
+            c = fetch(conn)
             if c.any():
-                sets_l.append(_fetch(S)[c])
+                sets_l.append(fetch(S)[c])
         if sets_l:
             return np.concatenate(sets_l)
         return np.zeros(0, np.int32)
@@ -461,7 +453,7 @@ class ExactEngine:
             pad[: len(sl)] = sl
             cand = _expand_chunk(jnp.asarray(pad), jnp.int32(len(sl)),
                                  self.dg.adj, nmax=self.nmax, cap=cap)
-            c = _fetch(cand).ravel()
+            c = fetch(cand).ravel()
             cand_l.append(c[c != 0])
         return np.unique(np.concatenate(cand_l)) if cand_l else np.zeros(0, np.int32)
 
@@ -522,7 +514,7 @@ class ExactEngine:
                     self.dg.adj, self.memo_cost, self.memo_rows, *self._targs,
                     nmax=self.nmax, chunk=self.chunk, nseg=self.chunk + 1,
                     typed=self.typed)
-                sc, sl, ev, cc = _fetch((sc, sl, ev, cc))
+                sc, sl, ev, cc = fetch((sc, sl, ev, cc))
                 self.counters.evaluated += int(ev)
                 self.counters.ccp += int(cc)
                 _merge_best(best_cost, best_left, lane0 >> i, sc, sl)
@@ -549,7 +541,7 @@ class ExactEngine:
                     self.memo_cost, self.memo_rows, *self._targs,
                     nmax=self.nmax, chunk=self.chunk, nseg=self.chunk + 1,
                     typed=self.typed)
-                sc, sl, ev, cc = _fetch((sc, sl, ev, cc))
+                sc, sl, ev, cc = fetch((sc, sl, ev, cc))
                 self.counters.evaluated += int(ev)
                 self.counters.ccp += int(cc)
                 _merge_best(best_cost, best_left, lane0 // m, sc, sl)
@@ -559,7 +551,7 @@ class ExactEngine:
     def _find_blocks_host(self, sets_np):
         """Phase A: per-set blocks -> compacted (set, block) pair arrays
         (shared host driver in ``blocks.np_pairs_for_sets``)."""
-        ps, pb = bl.np_pairs_for_sets(
+        ps, pb, _ = bl.np_pairs_for_sets(
             sets_np, self.g, self.dg.adj, self.eu_idx, self.ev_idx,
             self.edge_live, nmax=self.nmax, emax=self.emax,
             cyc_cap=self.cyc_cap)
@@ -604,7 +596,7 @@ class ExactEngine:
                     self.dg.adj, self.memo_cost, self.memo_rows, *self._targs,
                     nmax=self.nmax, chunk=self.chunk, pcap=pcap,
                     typed=self.typed)
-                sc, sl, ev, cc = _fetch((sc, sl, ev, cc))
+                sc, sl, ev, cc = fetch((sc, sl, ev, cc))
                 self.counters.evaluated += int(ev)
                 self.counters.ccp += int(cc)
                 scn = sc[:npair]
@@ -644,7 +636,7 @@ class ExactEngine:
                         self.memo_rows, self.dg.card_l2, self.dg.emask_u,
                         self.dg.emask_v, self.dg.esel_l2,
                         nmax=self.nmax, chunk=self.chunk)
-                    S, cn, A, ev, cc = _fetch((S, cand, A, ev, cc))
+                    S, cn, A, ev, cc = fetch((S, cand, A, ev, cc))
                     self.counters.evaluated += int(ev)
                     self.counters.ccp += int(cc)
                     fin = np.isfinite(cn)
@@ -663,9 +655,9 @@ class ExactEngine:
     # ------------------------------------------------------------ finish ---
     def result(self, algorithm: str, t0: float) -> OptimizeResult:
         full = self.g.full_set
-        cost = float(_fetch(self.memo_cost[full]))
+        cost = float(fetch(self.memo_cost[full]))
         if np.isfinite(cost):
-            p = extract_plan(full, _fetch(self.memo_left), self.g)
+            p = extract_plan(full, fetch(self.memo_left), self.g)
             return OptimizeResult(plan=p, cost=cost, counters=self.counters,
                                   algorithm=algorithm,
                                   wall_s=time.perf_counter() - t0,
@@ -676,7 +668,7 @@ class ExactEngine:
         # committed memo prefix with a GOO completion (anytime contract)
         from ..heuristics.idp import stitch_partial_memo
         p, c, dinfo = stitch_partial_memo(
-            self.g, *_fetch((self.memo_cost, self.memo_left)))
+            self.g, *fetch((self.memo_cost, self.memo_left)))
         r = OptimizeResult(plan=p, cost=c, counters=self.counters,
                            algorithm=algorithm,
                            wall_s=time.perf_counter() - t0,

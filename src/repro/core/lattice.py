@@ -73,13 +73,13 @@ from .batch import (PEND_WINDOW, _CLIP, _LevelLoop, _beval_dpsub_chunk,
                     _beval_general_chunk, _beval_tree_chunk, _bfilter_chunk,
                     _lane_space)
 from .config import UNSET, OptimizerConfig, resolve_config
-from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap, _fetch,
+from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap,
                      _merge_best, _merge_scattered, _use_pallas,
                      _use_pipeline)
 from .exec_cache import EXEC
 from .joingraph import JoinGraph, typed_edge_arrays
 from .plan import Counters, OptimizeResult, extract_plan
-from .telemetry import span
+from .telemetry import fetch, span
 from .shard import (BATCH_AXIS, _exec_key, _set_drop, _sharded, batch_mesh,
                     mesh_size)
 
@@ -140,6 +140,8 @@ class LatticeShardedEngine(_LevelLoop):
         self.degraded: dict | None = None
         self.collectives = 0               # min_left_commit dispatches
         self.chunks_dispatched = 0         # telemetry: chunk dispatch tally
+        self.blocks_sets = 0               # phase A: sets / launched slots
+        self.blocks_slots = 0
         self._exec_keys: set[tuple] = set()
         self._wall = 0.0
         self.counters = [Counters()]
@@ -313,7 +315,7 @@ class LatticeShardedEngine(_LevelLoop):
     def _filter_drain(self, ctx: dict, limit: int) -> None:
         pend, per_dev = ctx["pend"], ctx["per_dev"]
         while len(pend) > limit:
-            Sn, c, _ = _fetch(pend.popleft())
+            Sn, c, _ = fetch(pend.popleft())
             for d in range(self.D):
                 if c[d].any():
                     per_dev[d].append(Sn[d][c[d]])
@@ -392,7 +394,7 @@ class LatticeShardedEngine(_LevelLoop):
         pend, sizes = ctx["pend"], ctx["sizes"]
         while len(pend) > limit:
             c0, seg0, out = pend.popleft()
-            scn, sln, evn, ccpn = _fetch(out)
+            scn, sln, evn, ccpn = fetch(out)
             ctx["ev"] += evn
             ctx["ccp"] += ccpn
             for d in range(self.D):
@@ -416,9 +418,11 @@ class LatticeShardedEngine(_LevelLoop):
             z = np.zeros(0, np.int32)
             return z, z, np.zeros(0, np.int64)
         adj_q, eu_q, ev_q, eliv_q = self._phase_a_row
-        ps, pb = bl.np_pairs_for_sets(sets_np, self.g, adj_q, eu_q, ev_q,
-                                      eliv_q, nmax=self.nmax, emax=self.emax,
-                                      cyc_cap=self.cyc_cap)
+        ps, pb, slots = bl.np_pairs_for_sets(
+            sets_np, self.g, adj_q, eu_q, ev_q, eliv_q, nmax=self.nmax,
+            emax=self.emax, cyc_cap=self.cyc_cap)
+        self.blocks_sets += len(sets_np)
+        self.blocks_slots += slots
         pk = np.searchsorted(sets_np, ps).astype(np.int64)
         return ps, pb, pk
 
@@ -487,7 +491,7 @@ class LatticeShardedEngine(_LevelLoop):
         pend, pk = ctx["pend"], ctx["pk"]
         while len(pend) > limit:
             p0s, npairs, out = pend.popleft()
-            scn_all, sln_all, evn, ccpn = _fetch(out)
+            scn_all, sln_all, evn, ccpn = fetch(out)
             ctx["ev"] += evn
             ctx["ccp"] += ccpn
             for d in range(self.D):
@@ -524,7 +528,7 @@ class LatticeShardedEngine(_LevelLoop):
         ``tests/test_lattice_shard.py`` asserts it) and extract the plan."""
         t0 = time.perf_counter()
         g = self.g
-        cost_all, left_all = _fetch((self.memo_cost, self.memo_left))
+        cost_all, left_all = fetch((self.memo_cost, self.memo_left))
         cost = float(cost_all[0, g.full_set])
         wall = self._wall + time.perf_counter() - t0
         if np.isfinite(cost):

@@ -82,13 +82,13 @@ from . import unrank as ur
 from .batch import (NMAX_BATCH, PEND_WINDOW, _CLIP, _LevelLoop, _bcap,
                     _beval_dpsub_chunk, _beval_general_chunk,
                     _beval_tree_chunk, _bfilter_chunk)
-from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap, _fetch,
+from .engine import (CHUNK, CYC_CAP_DEFAULT, INF, _cap,
                      _merge_best, _merge_scattered, _use_pallas,
                      _use_pipeline)
 from .exec_cache import EXEC
 from .joingraph import JoinGraph, typed_edge_arrays
 from .plan import Counters, OptimizeResult, extract_plan
-from .telemetry import span
+from .telemetry import fetch, span
 
 BATCH_AXIS = "batch"
 
@@ -255,6 +255,8 @@ class ShardedBatchEngine(_LevelLoop):
         self._deadline_at: float | None = None
         self.degraded: dict | None = None
         self.chunks_dispatched = 0
+        self.blocks_sets = 0               # phase A: sets / launched slots
+        self.blocks_slots = 0
         self._exec_keys: set[tuple] = set()
         self._wall = 0.0
         self.B = len(graphs)
@@ -440,7 +442,7 @@ class ShardedBatchEngine(_LevelLoop):
         fused ``device_get`` per chunk covers all D shards)."""
         pend, per_q = ctx["pend"], ctx["per_q"]
         while len(pend) > limit:
-            Sn, c, qn = _fetch(pend.popleft())
+            Sn, c, qn = fetch(pend.popleft())
             for d in range(self.D):
                 if c[d].any():
                     Sc = Sn[d][c[d]]
@@ -598,7 +600,7 @@ class ShardedBatchEngine(_LevelLoop):
         pend = ctx["pend"]
         while len(pend) > limit:
             lane0, seg0, out = pend.popleft()
-            scn, sln, evn, ccpn = _fetch(out)
+            scn, sln, evn, ccpn = fetch(out)
             ctx["ev"] += evn[:, :Bs]
             ctx["ccp"] += ccpn[:, :Bs]
             for d in range(self.D):
@@ -627,9 +629,11 @@ class ShardedBatchEngine(_LevelLoop):
                     continue
                 g = self.shard_graphs[d][q]
                 adj_q, eu_q, ev_q, eliv_q = self._phase_a_rows[d][q]
-                ps_q, pb_q = bl.np_pairs_for_sets(
+                ps_q, pb_q, slots = bl.np_pairs_for_sets(
                     sets_q, g, adj_q, eu_q, ev_q, eliv_q,
                     nmax=self.nmax, emax=self.emax, cyc_cap=self.cyc_cap)
+                self.blocks_sets += len(sets_q)
+                self.blocks_slots += slots
                 ps_l.append(ps_q)
                 pb_l.append(pb_q)
                 pq_l.append(np.full(len(ps_q), q, np.int32))
@@ -711,7 +715,7 @@ class ShardedBatchEngine(_LevelLoop):
         pend = ctx["pend"]
         while len(pend) > limit:
             p0s, npairs, out = pend.popleft()
-            scn_all, sln_all, evn, ccpn = _fetch(out)
+            scn_all, sln_all, evn, ccpn = fetch(out)
             ctx["ev"] += evn[:, :Bs]
             ctx["ccp"] += ccpn[:, :Bs]
             for d in range(self.D):
@@ -748,7 +752,7 @@ class ShardedBatchEngine(_LevelLoop):
         """Fetch the stacked memo and extract per-query results (see
         ``BatchEngine.collect``)."""
         t0 = time.perf_counter()
-        cost_all, left_all = _fetch((self.memo_cost, self.memo_left))
+        cost_all, left_all = fetch((self.memo_cost, self.memo_left))
         out = []
         wall = self._wall + time.perf_counter() - t0
         for qi, g in enumerate(self.graphs):

@@ -21,11 +21,14 @@ STATS reply, which aggregates them across requests.  See
 profiler's host timeline, the clock the device events share.  The names
 are ``<layer>.<step>`` (``daemon.job``, ``service.admit``,
 ``level.fetch``, ``uniondp.reopt``; the list is in ``docs/telemetry.md``).
+:func:`fetch` is the level loops' one blocking device-to-host copy, under
+``level.fetch``.
 """
 from __future__ import annotations
 
 import dataclasses
 
+import jax
 from jax.profiler import TraceAnnotation
 
 
@@ -34,6 +37,14 @@ def span(name: str, **meta) -> TraceAnnotation:
     arguments, kept by the profiler when a trace runs (``jax.profiler``).
     With no trace running it costs one object and a flag test."""
     return TraceAnnotation(name, **meta)
+
+
+def fetch(tree):
+    """Blocking device-to-host copy of ``tree`` (an array or a tuple of
+    them) under a ``level.fetch`` span: one host round trip of a level
+    loop."""
+    with span("level.fetch"):
+        return jax.device_get(tree)
 
 
 @dataclasses.dataclass
@@ -47,6 +58,8 @@ class FlightTelemetry:
     ccp_lanes: int = 0        # raw candidate lanes before filtering
     chunk: int = 0            # chunk size the flight ran with
     chunks: int = 0           # chunk dispatches across all levels/stages
+    blocks_sets: int = 0      # sets given to phase A's block finding
+    blocks_slots: int = 0     # set slots its launches held (>= blocks_sets)
     retraces: int = 0         # executable-cache retraces charged to the flight
     result_cost: float = 0.0  # sum of final plan costs (f32 exact-min costs)
     wall_s: float = 0.0       # run_levels wall (service: stamped in _finalize)
@@ -69,7 +82,8 @@ def capture(eng, results, *, nmax: int, queries: int, lattice: bool = False,
     """Build a :class:`FlightTelemetry` from a finished engine.
 
     ``eng`` is any engine exposing ``algorithm``, ``chunk``, ``counters``
-    (list of per-graph ``Counters``), ``chunks_dispatched``, and ``stats``
+    (list of per-graph ``Counters``), ``chunks_dispatched``,
+    ``blocks_sets``, ``blocks_slots``, and ``stats``
     (the ``exec_cache.stats_for`` dict); ``results`` the collected
     ``PlanResult`` list (only ``.cost`` is read).  Missing attributes
     record as zeros so stand-in engines (service test spies) still
@@ -88,6 +102,8 @@ def capture(eng, results, *, nmax: int, queries: int, lattice: bool = False,
         ccp_lanes=ccp,
         chunk=int(getattr(eng, "chunk", 0) or 0),
         chunks=int(getattr(eng, "chunks_dispatched", 0)),
+        blocks_sets=int(getattr(eng, "blocks_sets", 0)),
+        blocks_slots=int(getattr(eng, "blocks_slots", 0)),
         retraces=int(stats.get("retraces", 0)),
         result_cost=float(sum(float(r.cost) for r in results)),
         wall_s=float(wall_s),
@@ -108,6 +124,8 @@ def aggregate(records) -> dict:
         "evaluated_lanes": sum(r.evaluated_lanes for r in recs),
         "ccp_lanes": sum(r.ccp_lanes for r in recs),
         "chunks": sum(r.chunks for r in recs),
+        "blocks_sets": sum(r.blocks_sets for r in recs),
+        "blocks_slots": sum(r.blocks_slots for r in recs),
         "retraces": sum(r.retraces for r in recs),
         "result_cost": float(sum(r.result_cost for r in recs)),
         "wall_s": float(sum(r.wall_s for r in recs)),

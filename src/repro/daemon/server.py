@@ -153,7 +153,8 @@ class OptimizerDaemon:
         # flight-telemetry roll-up (telemetry.aggregate shape, summed
         # across every finalized flight of every request)
         self._telemetry = {"flights": 0, "queries": 0, "evaluated_lanes": 0,
-                           "ccp_lanes": 0, "chunks": 0, "retraces": 0}
+                           "ccp_lanes": 0, "chunks": 0, "retraces": 0,
+                           "blocks_sets": 0, "blocks_slots": 0}
 
     # ------------------------------------------------------------ lifecycle -
     def start(self) -> None:

@@ -143,6 +143,12 @@ class TestDaemonEndToEnd:
             for k in ("p50", "p95", "p99"):
                 assert st["request_wall_s"][k] >= 0.0
 
+    def test_stats_carry_phase_a_tallies(self, daemon):
+        with DaemonClient(socket_path=daemon.address) as c:
+            c.optimize([gen.cycle(7, 1)])
+            tele = c.stats()["telemetry"]
+        assert 0 < tele["blocks_sets"] <= tele["blocks_slots"]
+
     def test_unknown_op_keeps_connection_usable(self, daemon):
         with DaemonClient(socket_path=daemon.address) as c:
             with pytest.raises(Exception, match="unknown op"):

@@ -29,7 +29,8 @@ NAMES = {"daemon.idle", "daemon.job", "daemon.decode", "daemon.encode",
 
 # span -> the spans it may sit directly inside (None: a line's top level)
 PARENTS = {
-    "level.fetch": {"level.filter", "level.eval", "engine.collect"},
+    "level.fetch": {"level.filter", "level.pairs", "level.eval",
+                    "engine.collect"},
     "level.filter": {"engine.levels"}, "level.register": {"engine.levels"},
     "level.pairs": {"engine.levels"}, "level.eval": {"engine.levels"},
     "engine.setup": {"daemon.job", "uniondp.solve", "uniondp.partition",
@@ -113,6 +114,7 @@ def test_spans_nest_as_documented(traced):
         assert parent in PARENTS[name], (name, parent)
     inside = {(n, p) for n, p in pairs}
     assert ("level.fetch", "level.eval") in inside
+    assert ("level.fetch", "level.pairs") in inside
     assert ("engine.levels", "daemon.job") in inside
     assert ("engine.levels", "uniondp.partition") in inside
     assert ("engine.levels", "uniondp.reopt") in inside
