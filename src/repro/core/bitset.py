@@ -24,13 +24,19 @@ NMAX_HARD = 30  # int32-sign-safe ceiling for exact algorithms
 
 
 def nmax_bucket(n: int) -> int:
-    """Static NMAX bucket for a query of ``n`` relations (limits recompiles)."""
+    """Static NMAX bucket for a query of ``n`` relations (limits recompiles).
+
+    Up to 24 relations the buckets are 8, 16 and 24, so queries of nearby
+    sizes share compiled executables.  Above 24 the bucket is ``n`` itself:
+    only the solo engine runs there, its dense memo holds ``1 << nmax``
+    entries, and a coarse 30 bucket would ask 16 GiB of a 25-relation
+    query where 512 MiB suffice."""
     if n > NMAX_HARD:
         raise ValueError(f"exact bitmap algorithms support n <= {NMAX_HARD}, got {n}")
-    for b in (8, 16, 24, 30):
+    for b in (8, 16, 24):
         if n <= b:
             return b
-    return NMAX_HARD
+    return n
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from . import bitset as bs
-from .telemetry import fetch
+from .telemetry import fetch_in_waves
 
 
 # ------------------------------------------------------------------ oracle --
@@ -129,32 +129,38 @@ def _bfs_tree(S, adj, nmax: int):
     return parent, depth
 
 
+def _pick(vec, idx, nmax: int):
+    """``vec[idx]`` for a per-set ``vec`` of ``nmax`` entries, by a chain
+    of selects: under the vmap over sets a gather from so small a table
+    costs a TPU more than the ``nmax`` selects."""
+    out = jnp.zeros(idx.shape, vec.dtype)
+    for v in range(nmax):
+        out = jnp.where(idx == v, vec[v], out)
+    return out
+
+
 def _fundamental_cycles(S, parent, depth, eu_idx, ev_idx, active, nmax: int):
-    """Vertex bitmap of the fundamental cycle of each (non-tree) edge."""
+    """Vertex bitmap of the fundamental cycle of each (non-tree) edge: both
+    endpoints climb the BFS tree to their LCA, every edge at once."""
 
-    def one_edge(u, v, act):
-        def body(_, st):
-            a, b, cyc = st
-            da = depth[a]
-            db = depth[b]
-            # move deeper endpoint(s) up; when equal depth and a != b move both
-            step_a = (a != b) & (da >= db)
-            step_b = (a != b) & (db > da)
-            both = (a != b) & (da == db)
-            cyc = cyc | (jnp.int32(1) << a) | (jnp.int32(1) << b)
-            na = jnp.where(step_a | both, parent[a], a)
-            nb = jnp.where(step_b | both, parent[b], b)
-            na = jnp.maximum(na, 0)
-            nb = jnp.maximum(nb, 0)
-            return na, nb, cyc
+    def body(_, st):
+        a, b, cyc = st
+        da = _pick(depth, a, nmax)
+        db = _pick(depth, b, nmax)
+        # move deeper endpoint(s) up; when equal depth and a != b move both
+        step_a = (a != b) & (da >= db)
+        step_b = (a != b) & (db > da)
+        both = (a != b) & (da == db)
+        cyc = cyc | (jnp.int32(1) << a) | (jnp.int32(1) << b)
+        na = jnp.where(step_a | both, _pick(parent, a, nmax), a)
+        nb = jnp.where(step_b | both, _pick(parent, b, nmax), b)
+        return jnp.maximum(na, 0), jnp.maximum(nb, 0), cyc
 
-        a0 = jnp.maximum(u, 0)
-        b0 = jnp.maximum(v, 0)
-        a, b, cyc = jax.lax.fori_loop(0, 2 * nmax, body, (a0, b0, jnp.int32(0)))
-        cyc = cyc | (jnp.int32(1) << a)  # the LCA
-        return jnp.where(act, cyc, jnp.int32(0))
-
-    return jax.vmap(one_edge)(eu_idx, ev_idx, active)
+    a, b, cyc = jax.lax.fori_loop(
+        0, 2 * nmax, body, (jnp.maximum(eu_idx, 0), jnp.maximum(ev_idx, 0),
+                            jnp.zeros(eu_idx.shape, jnp.int32)))
+    cyc = cyc | (jnp.int32(1) << a)  # the LCA
+    return jnp.where(active, cyc, jnp.int32(0))
 
 
 def _merge_cycles(cycles, emax: int):
@@ -190,8 +196,8 @@ def find_blocks_one(S, adj, eu_idx, ev_idx, edge_live, nmax: int):
     ubit = jnp.where(eu_idx >= 0, jnp.int32(1) << jnp.maximum(eu_idx, 0), 0)
     vbit = jnp.where(ev_idx >= 0, jnp.int32(1) << jnp.maximum(ev_idx, 0), 0)
     in_s = edge_live & ((ubit & S) != 0) & ((vbit & S) != 0)
-    pu = parent[jnp.maximum(eu_idx, 0)]
-    pv = parent[jnp.maximum(ev_idx, 0)]
+    pu = _pick(parent, jnp.maximum(eu_idx, 0), nmax)
+    pv = _pick(parent, jnp.maximum(ev_idx, 0), nmax)
     is_tree = in_s & ((pu == ev_idx) | (pv == eu_idx))
     non_tree = in_s & ~is_tree
     cycles = _fundamental_cycles(S, parent, depth, eu_idx, ev_idx, non_tree, nmax)
@@ -237,14 +243,14 @@ SCAP = 4096       # sets per full phase-A launch
 SCAP_MIN = 256    # fewest set slots of a launch (bounds the compile keys)
 
 
-def _launches(sets_np):
+def _launches(sets_np, scap_min: int = SCAP_MIN):
     """Phase A's launches over a level: ``(s0, sets, padded)`` per launch.
     Full launches hold ``SCAP`` sets; the last (or only) one is sized to
     the next power of two at or above its set count, at least
-    ``SCAP_MIN``, so a small level does not pay for 4096 slots."""
+    ``scap_min``, so a small level does not pay for 4096 slots."""
     for s0 in range(0, len(sets_np), SCAP):
         sl = sets_np[s0: s0 + SCAP]
-        scap = max(SCAP_MIN, 1 << (len(sl) - 1).bit_length())
+        scap = max(scap_min, 1 << (len(sl) - 1).bit_length())
         pad = np.zeros(scap, np.int32)
         pad[: len(sl)] = sl
         yield s0, sl, pad
@@ -262,15 +268,17 @@ def blocks_chunk(sets_pad, n_valid, adj, eu_idx, ev_idx, edge_live,
         ubit = jnp.where(eu_idx >= 0, jnp.int32(1) << jnp.maximum(eu_idx, 0), 0)
         vbit = jnp.where(ev_idx >= 0, jnp.int32(1) << jnp.maximum(ev_idx, 0), 0)
         in_s = edge_live & ((ubit & s) != 0) & ((vbit & s) != 0)
-        pu = parent[jnp.maximum(eu_idx, 0)]
-        pv = parent[jnp.maximum(ev_idx, 0)]
+        pu = _pick(parent, jnp.maximum(eu_idx, 0), nmax)
+        pv = _pick(parent, jnp.maximum(ev_idx, 0), nmax)
         non_tree = in_s & ~((pu == ev_idx) | (pv == eu_idx))
-        # compact non-tree edge endpoints into cyc_cap slots
+        # compact non-tree edge endpoints into cyc_cap slots (by selects:
+        # slot c takes the one non-tree edge that counts c before it)
         pos = jnp.cumsum(non_tree.astype(jnp.int32)) - 1
-        slot = jnp.where(non_tree, pos, cyc_cap)
-        cu = jnp.full(cyc_cap, -1, jnp.int32).at[slot].set(eu_idx, mode="drop")
-        cv = jnp.full(cyc_cap, -1, jnp.int32).at[slot].set(ev_idx, mode="drop")
-        act = jnp.zeros(cyc_cap, bool).at[slot].set(non_tree, mode="drop")
+        match = non_tree[None, :] & (pos[None, :]
+                                     == jnp.arange(cyc_cap)[:, None])
+        act = jnp.any(match, axis=1)
+        cu = jnp.where(act, jnp.where(match, eu_idx[None, :], 0).sum(1), -1)
+        cv = jnp.where(act, jnp.where(match, ev_idx[None, :], 0).sum(1), -1)
         cycles = _fundamental_cycles(s, parent, depth, cu, cv, act, nmax)
         merged = _merge_cycles(cycles, cyc_cap)
         shifts = jnp.arange(nmax, dtype=jnp.int32)
@@ -290,10 +298,12 @@ def blocks_chunk(sets_pad, n_valid, adj, eu_idx, ev_idx, edge_live,
 
 
 def np_pairs_for_sets(sets_np, g, adj, eu_idx, ev_idx, edge_live,
-                      *, nmax: int, emax: int, cyc_cap: int):
+                      *, nmax: int, emax: int, cyc_cap: int,
+                      scap_min: int = SCAP_MIN):
     """Phase A on the host: compacted (set, block) pair arrays for a level,
     and the set slots its launches held (``_launches``; the engines tally
     them as ``blocks_slots`` beside ``blocks_sets`` += ``len(sets_np)``).
+    Launches are fetched a wave at a time (``telemetry.fetch_in_waves``).
 
     ``adj``/``eu_idx``/``ev_idx``/``edge_live`` are the device-side arrays of
     the query (one query at a time — BatchEngine loops its sub-batch here,
@@ -308,13 +318,13 @@ def np_pairs_for_sets(sets_np, g, adj, eu_idx, ev_idx, edge_live,
         # static fundamental-cycle slots to the query, not the ceiling
         # (perf log: 24 -> mu slots cut phase A ~4x on near-tree graphs)
         eff_cap = max(1, min(cyc_cap, mu))
-        for _, sl, pad in _launches(sets_np):
-            slots += len(pad)
-            merged, bridge = blocks_chunk(
-                jnp.asarray(pad), jnp.int32(len(sl)), adj,
-                eu_idx, ev_idx, edge_live,
-                nmax=nmax, emax=emax, cyc_cap=eff_cap, scap=len(pad))
-            mg, br = fetch((merged, bridge))
+        for sl, (mg, br) in fetch_in_waves(
+                lambda launch: (launch[1], blocks_chunk(
+                    launch[2], np.int32(len(launch[1])), adj,
+                    eu_idx, ev_idx, edge_live, nmax=nmax, emax=emax,
+                    cyc_cap=eff_cap, scap=len(launch[2]))),
+                _launches(sets_np, scap_min)):
+            slots += len(mg)
             both = np.concatenate([mg[: len(sl)], br[: len(sl)]], axis=1)
             snp = np.repeat(sl[:, None], both.shape[1], axis=1)
             nz = both != 0
@@ -324,10 +334,12 @@ def np_pairs_for_sets(sets_np, g, adj, eu_idx, ev_idx, edge_live,
         # dense path: no-cut-vertex sets are single blocks (cliques);
         # rare cut-vertex sets fall back to the host oracle
         flags = np.zeros(len(sets_np), bool)
-        for s0, sl, pad in _launches(sets_np):
-            slots += len(pad)
-            hc = has_cut_vertex_batch(jnp.asarray(pad), adj, nmax)
-            flags[s0: s0 + len(sl)] = fetch(hc)[: len(sl)]
+        for (s0, n), hc in fetch_in_waves(
+                lambda launch: ((launch[0], len(launch[1])),
+                                has_cut_vertex_batch(launch[2], adj, nmax)),
+                _launches(sets_np, scap_min)):
+            slots += len(hc)
+            flags[s0: s0 + n] = hc[:n]
         easy = sets_np[~flags]
         pair_set.append(easy)
         pair_block.append(easy)

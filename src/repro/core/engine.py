@@ -42,7 +42,7 @@ from .config import (CHUNK, CYC_CAP_DEFAULT, UNSET, OptimizerConfig,
                      alias_kwarg, resolve_config)
 from .joingraph import DeviceGraph, JoinGraph
 from .plan import Counters, OptimizeResult, extract_plan
-from .telemetry import fetch, span
+from .telemetry import fetch, fetch_in_waves, span
 
 INF = np.float32(np.inf)
 
@@ -75,8 +75,10 @@ def _cap(n: int, lo: int = 1024) -> int:
 
 @partial(jax.jit, static_argnames=("nmax", "chunk"))
 def _filter_chunk(rank0, total, k, binom, adj, *, nmax: int, chunk: int):
-    """unrank + connectivity filter (rows are costed on the host afterwards,
-    via the canonical ``cost.np_rows_for_sets`` shared with BatchEngine)."""
+    """unrank + connectivity filter: each lane's k-subset where it is
+    connected, else 0 (no k-subset of k >= 1 is 0).  Rows are costed on the
+    host afterwards, via the canonical ``cost.np_rows_for_sets`` shared
+    with BatchEngine."""
     t = jnp.arange(chunk, dtype=jnp.int32)
     ranks = rank0 + t
     mask = ranks < total
@@ -86,7 +88,7 @@ def _filter_chunk(rank0, total, k, binom, adj, *, nmax: int, chunk: int):
         conn = (_ko.connectivity(S, adj, nmax) != 0) & mask
     else:
         conn = bs.is_connected(S, adj) & mask
-    return S, conn
+    return jnp.where(conn, S, 0)
 
 
 @partial(jax.jit, static_argnames=("nmax", "cap"))
@@ -110,6 +112,21 @@ def _scatter_f32(buf, idx, val, *, size: int, cap: int):
 @partial(jax.jit, static_argnames=("size", "cap"), donate_argnums=(0,))
 def _scatter_i32(buf, idx, val, *, size: int, cap: int):
     return buf.at[idx].set(val, mode="drop")
+
+
+# a solo memo write is padded to SCATTER_SMALL entries, or split into pieces
+# of SCATTER_BIG: few executables, since each takes the compiler a while
+SCATTER_SMALL, SCATTER_BIG = 1 << 12, 1 << 16
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _scatter_sorted(buf, idx, val):
+    """``buf[idx] = val`` for ascending, distinct ``idx``; those past the
+    end are dropped.  The promise spares XLA its handling of repeated
+    indices: compiling a 2^16-entry scatter for a v5e takes 0.1 s so
+    made, and 8 s without it."""
+    return buf.at[idx].set(val, mode="drop", indices_are_sorted=True,
+                           unique_indices=True)
 
 
 def _lane_cost(S_left, S_right, S_rows, memo_cost, memo_rows):
@@ -246,16 +263,37 @@ def _eval_tree_chunk(all_sets, level_off, base_set, base_e, m, lane_count,
     return seg_cost, seg_left, evaluated.sum(), ccp.sum()
 
 
+def _lane_pairs(off_local, n_pairs, chunk: int):
+    """The pair of every lane of a chunk, ``searchsorted(off_local, t,
+    side="right") - 1`` for the ascending pair offsets ``off_local``: the
+    count of offsets at or below the lane, by a scatter of the offsets and
+    a prefix sum.  A binary search's gathers cost a v5e 3 ms more a
+    32,768-lane chunk.  Only the first pair can start before the chunk,
+    and those past its end scatter to slots of their own, so the indices
+    are ascending and distinct."""
+    pcap = off_local.shape[0]
+    idx = jnp.where(off_local >= chunk,
+                    chunk + jnp.arange(pcap, dtype=jnp.int32),
+                    jnp.maximum(off_local, 0))
+    starts = jnp.zeros(chunk + pcap, jnp.int32).at[idx].set(
+        1, indices_are_sorted=True, unique_indices=True)
+    return jnp.clip(jnp.cumsum(starts[:chunk]) - 1, 0, n_pairs - 1)
+
+
 @partial(jax.jit, static_argnames=("nmax", "chunk", "pcap", "typed"))
-def _eval_general_chunk(pair_set, pair_block, off_local, n_pairs, lane_count,
+def _eval_general_chunk(pairs, n_pairs, lane_count,
                         adj, memo_cost, memo_rows,
                         ekind=None, elm=None, erm=None, etes_l=None, etes_r=None,
                         *, nmax: int, chunk: int, pcap: int,
                         typed: bool = False):
+    """MPDP-general evaluate of one chunk.  ``pairs`` is i32[3, pcap]: the
+    chunk's pair sets, blocks and lane offsets, one transfer.  Returns one
+    i32[2 * pcap + 2]: the per-pair best costs (f32 bits), their left
+    operands, and the evaluated and CCP lane counts, one fetch."""
+    pair_set, pair_block, off_local = pairs[0], pairs[1], pairs[2]
     t = jnp.arange(chunk, dtype=jnp.int32)
     live = t < lane_count
-    p = jnp.searchsorted(off_local, t, side="right").astype(jnp.int32) - 1
-    p = jnp.clip(p, 0, n_pairs - 1)
+    p = _lane_pairs(off_local, n_pairs, chunk)
     r = t - off_local[p]
     S = pair_set[p]
     block = pair_block[p]
@@ -268,7 +306,7 @@ def _eval_general_chunk(pair_set, pair_block, off_local, n_pairs, lane_count,
     ccp_blk = enum_ok & conn_l & conn_r & cross
     S_left = bs.grow(lb, S & ~rb, adj)                     # Alg.3 line 17
     S_right = S & ~S_left
-    rows_S = memo_rows[S]
+    rows_S = memo_rows[pair_set][p]                        # one memo read a pair
     if typed:
         cand, lbx = _typed_lane_cost(
             S_left, S_right, rows_S, ccp_blk, memo_cost[S_left],
@@ -279,7 +317,9 @@ def _eval_general_chunk(pair_set, pair_block, off_local, n_pairs, lane_count,
                                              memo_cost, memo_rows), INF)
         lbx = S_left
     seg_cost, seg_left = _prune(p, cand, lbx, pcap)
-    return seg_cost, seg_left, enum_ok.sum(), ccp_blk.sum()
+    return jnp.concatenate([
+        jax.lax.bitcast_convert_type(seg_cost, jnp.int32), seg_left,
+        jnp.stack([enum_ok.sum(), ccp_blk.sum()]).astype(jnp.int32)])
 
 
 @partial(jax.jit, static_argnames=("nmax", "chunk"))
@@ -313,6 +353,49 @@ def _eval_dpsize_chunk(all_sets, off_a, off_b, count_b, base_a, base_b,
 
 
 # ============================================================== host driver ==
+
+# lanes of one unrank-filter launch (at least the engine's chunk): a lane
+# costs the filter little, so a launch of 2^17 lanes instead of 2^15 saves
+# three quarters of the launches' dispatches and profiler events (272
+# launches a 25-relation query instead of 1,040)
+FILTER_CHUNK = 1 << 17
+
+class MemoTooLarge(ValueError):
+    """The solo engine's dense memo does not fit the device's free memory:
+    refused before anything is allocated."""
+
+    def __init__(self, n: int, need: int, free: int):
+        super().__init__(
+            f"the exact memo of a {n}-relation query needs {need} device "
+            f"bytes; the device has {free} free")
+        self.n, self.need, self.free = n, need, free
+
+
+def memo_bytes(nmax: int) -> int:
+    """Bytes of the solo engine's four dense ``1 << nmax`` tables (memo
+    cost, rows and left operand, and the packed level buffer), 4 B each."""
+    return 16 << nmax
+
+
+def memo_need_bytes(n: int, nmax: int) -> int:
+    """A lower bound on the device bytes a solo run holds at its peak: the
+    memo, plus one level's transfer buffers (set ids, positions, values and
+    scatter indices, 4 B each, padded to a power of two) at the largest
+    level, ``C(n, n // 2)`` sets.  Executables and the evaluate chunks'
+    buffers are not counted: at n = 25 a TPU v5e's measured peak was 14 %
+    above this, so a query just under the free bytes can still fail to
+    allocate."""
+    return memo_bytes(nmax) + 16 * _cap(comb(n, n // 2))
+
+
+def device_free_bytes() -> int | None:
+    """Free bytes of the default device, or None where it reports no memory
+    statistics (the CPU backend)."""
+    stats = jax.devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
 
 class ExactEngine:
     """Runs one exact algorithm (dpsub / mpdp / dpsize) over a JoinGraph."""
@@ -353,7 +436,10 @@ class ExactEngine:
             self._targs = ((self.dg.ekind, self.dg.elm, self.dg.erm,
                             self.dg.etes_l, self.dg.etes_r)
                            if self.typed else (None,) * 5)
-            self.counters = Counters()
+            self.counters = Counters(memo_bytes=memo_bytes(self.nmax))
+            need, free = memo_need_bytes(self.n, self.nmax), device_free_bytes()
+            if free is not None and need > free:
+                raise MemoTooLarge(self.n, need, free)
             self._init_memo()
 
     # ------------------------------------------------------------- memo ----
@@ -373,25 +459,31 @@ class ExactEngine:
         self._next_off = self.n
 
     def _scatter(self, sets_np, cost=None, rows=None, left=None):
-        cap = _cap(len(sets_np))
-        idx = np.full(cap, self.size, np.int32)  # OOB pad -> dropped
-        idx[: len(sets_np)] = sets_np
-        idx_d = jnp.asarray(idx)
-
-        def pad(x, dt):
-            b = np.zeros(cap, dt)
-            b[: len(sets_np)] = x
-            return jnp.asarray(b)
-
+        """Memo writes at ``sets_np``, ascending bitmaps (every level's sets
+        and leaves are)."""
         if cost is not None:
-            self.memo_cost = _scatter_f32(self.memo_cost, idx_d, pad(cost, np.float32),
-                                          size=self.size, cap=cap)
+            self.memo_cost = self._write(self.memo_cost, sets_np, cost)
         if rows is not None:
-            self.memo_rows = _scatter_f32(self.memo_rows, idx_d, pad(rows, np.float32),
-                                          size=self.size, cap=cap)
+            self.memo_rows = self._write(self.memo_rows, sets_np, rows)
         if left is not None:
-            self.memo_left = _scatter_i32(self.memo_left, idx_d, pad(left, np.int32),
-                                          size=self.size, cap=cap)
+            self.memo_left = self._write(self.memo_left, sets_np, left)
+
+    def _write(self, buf, idx, val):
+        """``buf[idx] = val`` for ascending, distinct ``idx``: in pieces of
+        at most ``SCATTER_BIG`` entries, each padded to ``SCATTER_SMALL``
+        or ``SCATTER_BIG`` with distinct indices past the memo's end, which
+        ``_scatter_sorted`` drops.  Two executables a dtype."""
+        if np.any(np.diff(idx) <= 0):
+            raise ValueError("memo writes need ascending, distinct indices")
+        for s0 in range(0, len(idx), SCATTER_BIG):
+            n = min(SCATTER_BIG, len(idx) - s0)
+            cap = SCATTER_SMALL if n <= SCATTER_SMALL else SCATTER_BIG
+            pi = self.size + np.arange(cap, dtype=np.int64)
+            pi[:n] = idx[s0: s0 + n]
+            pv = np.zeros(cap, buf.dtype)
+            pv[:n] = val[s0: s0 + n]
+            buf = _scatter_sorted(buf, pi.astype(np.int32), pv)
+        return buf
 
     # ------------------------------------------------------------ filter ---
     def _level_sets(self, i: int):
@@ -402,19 +494,15 @@ class ExactEngine:
             else:
                 sets_np = self._level_sets_unrank(i)
         self._prev_level = sets_np
+        self.counters.filter_sets += len(sets_np)
         # scatter rows for this level; register in the packed level buffer
         if len(sets_np):
             with span("level.register"):
                 self._scatter(sets_np,
                               rows=cm.np_rows_for_sets(sets_np, self.g))
-                cap = _cap(len(sets_np))
-                buf = np.zeros(cap, np.int32)
-                buf[: len(sets_np)] = sets_np
-                pos = np.full(cap, self.size, np.int32)
-                pos[: len(sets_np)] = self._next_off + np.arange(len(sets_np))
-                self.all_sets = _scatter_i32(self.all_sets, jnp.asarray(pos),
-                                             jnp.asarray(buf), size=self.size,
-                                             cap=cap)
+                self.all_sets = self._write(
+                    self.all_sets, self._next_off + np.arange(len(sets_np)),
+                    sets_np)
         self.level_off[i] = self._next_off
         self.level_cnt[i] = len(sets_np)
         self._next_off += len(sets_np)
@@ -423,14 +511,15 @@ class ExactEngine:
     def _level_sets_unrank(self, i: int):
         """Paper Alg.5: unrank the full C(n, i) space, mask connectivity."""
         total = comb(self.n, i)
+        chunk = max(self.chunk, FILTER_CHUNK)
         sets_l = []
-        for rank0 in range(0, total, self.chunk):
-            S, conn = _filter_chunk(
-                jnp.int32(rank0), jnp.int32(total), jnp.int32(i), self.binom,
-                self.dg.adj, nmax=self.nmax, chunk=self.chunk)
-            c = fetch(conn)
-            if c.any():
-                sets_l.append(fetch(S)[c])
+        for _, S in fetch_in_waves(
+                lambda rank0: (None, _filter_chunk(
+                    np.int32(rank0), np.int32(total), np.int32(i),
+                    self.binom, self.dg.adj, nmax=self.nmax, chunk=chunk)),
+                range(0, total, chunk)):
+            self.counters.filter_lanes += chunk
+            sets_l.append(S[S != 0])
         if sets_l:
             return np.concatenate(sets_l)
         return np.zeros(0, np.int32)
@@ -453,6 +542,7 @@ class ExactEngine:
             pad[: len(sl)] = sl
             cand = _expand_chunk(jnp.asarray(pad), jnp.int32(len(sl)),
                                  self.dg.adj, nmax=self.nmax, cap=cap)
+            self.counters.filter_lanes += cap * self.nmax
             c = fetch(cand).ravel()
             cand_l.append(c[c != 0])
         return np.unique(np.concatenate(cand_l)) if cand_l else np.zeros(0, np.int32)
@@ -506,15 +596,15 @@ class ExactEngine:
             best_cost = np.full(ns, INF, np.float32)
             best_left = np.zeros(ns, np.int32)
             off = self.level_off[i]
-            for lane0 in range(0, lanes, self.chunk):
-                cnt = min(self.chunk, lanes - lane0)
-                sc, sl, ev, cc = _eval_dpsub_chunk(
-                    self.all_sets, jnp.int32(off), jnp.int32(lane0 >> i),
-                    jnp.int32(lane0 & ((1 << i) - 1)), jnp.int32(i), jnp.int32(cnt),
-                    self.dg.adj, self.memo_cost, self.memo_rows, *self._targs,
-                    nmax=self.nmax, chunk=self.chunk, nseg=self.chunk + 1,
-                    typed=self.typed)
-                sc, sl, ev, cc = fetch((sc, sl, ev, cc))
+            for lane0, (sc, sl, ev, cc) in fetch_in_waves(
+                    lambda lane0: (lane0, _eval_dpsub_chunk(
+                        self.all_sets, np.int32(off), np.int32(lane0 >> i),
+                        np.int32(lane0 & ((1 << i) - 1)), np.int32(i),
+                        np.int32(min(self.chunk, lanes - lane0)),
+                        self.dg.adj, self.memo_cost, self.memo_rows,
+                        *self._targs, nmax=self.nmax, chunk=self.chunk,
+                        nseg=self.chunk + 1, typed=self.typed)),
+                    range(0, lanes, self.chunk)):
                 self.counters.evaluated += int(ev)
                 self.counters.ccp += int(cc)
                 _merge_best(best_cost, best_left, lane0 >> i, sc, sl)
@@ -532,16 +622,16 @@ class ExactEngine:
             best_cost = np.full(ns, INF, np.float32)
             best_left = np.zeros(ns, np.int32)
             off = self.level_off[i]
-            for lane0 in range(0, lanes, self.chunk):
-                cnt = min(self.chunk, lanes - lane0)
-                sc, sl, ev, cc = _eval_tree_chunk(
-                    self.all_sets, jnp.int32(off), jnp.int32(lane0 // m),
-                    jnp.int32(lane0 % m), jnp.int32(m), jnp.int32(cnt),
-                    self.dg.adj, self.dg.emask_u, self.dg.emask_v,
-                    self.memo_cost, self.memo_rows, *self._targs,
-                    nmax=self.nmax, chunk=self.chunk, nseg=self.chunk + 1,
-                    typed=self.typed)
-                sc, sl, ev, cc = fetch((sc, sl, ev, cc))
+            for lane0, (sc, sl, ev, cc) in fetch_in_waves(
+                    lambda lane0: (lane0, _eval_tree_chunk(
+                        self.all_sets, np.int32(off), np.int32(lane0 // m),
+                        np.int32(lane0 % m), np.int32(m),
+                        np.int32(min(self.chunk, lanes - lane0)),
+                        self.dg.adj, self.dg.emask_u, self.dg.emask_v,
+                        self.memo_cost, self.memo_rows, *self._targs,
+                        nmax=self.nmax, chunk=self.chunk,
+                        nseg=self.chunk + 1, typed=self.typed)),
+                    range(0, lanes, self.chunk)):
                 self.counters.evaluated += int(ev)
                 self.counters.ccp += int(cc)
                 _merge_best(best_cost, best_left, lane0 // m, sc, sl)
@@ -550,12 +640,32 @@ class ExactEngine:
     # ------------------------------------------------------- MPDP general --
     def _find_blocks_host(self, sets_np):
         """Phase A: per-set blocks -> compacted (set, block) pair arrays
-        (shared host driver in ``blocks.np_pairs_for_sets``)."""
+        (shared host driver in ``blocks.np_pairs_for_sets``).  Every launch
+        holds ``blocks.SCAP`` sets: one executable, and a padded launch
+        costs little beside the solo engine's levels."""
         ps, pb, _ = bl.np_pairs_for_sets(
             sets_np, self.g, self.dg.adj, self.eu_idx, self.ev_idx,
             self.edge_live, nmax=self.nmax, emax=self.emax,
-            cyc_cap=self.cyc_cap)
+            cyc_cap=self.cyc_cap, scap_min=bl.SCAP)
         return ps, pb
+
+    def _launch_general(self, ps, pb, offs, total: int, lane0: int):
+        """Dispatch the evaluate chunk of lanes ``[lane0, lane0 + chunk)``:
+        its pairs ``[p0, p1)`` and the chunk's device outputs."""
+        lane1 = min(lane0 + self.chunk, total)
+        p0 = int(np.searchsorted(offs, lane0, side="right")) - 1
+        p1 = int(np.searchsorted(offs, lane1, side="left"))
+        npair = p1 - p0
+        pcap = _cap(npair, 4096)                 # 3 executables at CHUNK
+        pairs = np.zeros((3, pcap), np.int32)
+        pairs[0, :npair] = ps[p0:p1]
+        pairs[1, :npair] = pb[p0:p1]
+        pairs[2] = 1 << 30                       # past the chunk's end
+        pairs[2, :npair] = np.clip(offs[p0:p1] - lane0, -(1 << 30), 1 << 30)
+        return (p0, p1), _eval_general_chunk(
+            pairs, np.int32(npair), np.int32(lane1 - lane0),
+            self.dg.adj, self.memo_cost, self.memo_rows, *self._targs,
+            nmax=self.nmax, chunk=self.chunk, pcap=pcap, typed=self.typed)
 
     def run_mpdp_general(self) -> None:
         self._run_levels(self._eval_mpdp_general)
@@ -577,33 +687,18 @@ class ExactEngine:
             best_cost = np.full(len(sets_np), INF, np.float32)
             best_left = np.zeros(len(sets_np), np.int32)
             k_all, c_all, l_all = [], [], []
-            for lane0 in range(0, total, self.chunk):
-                lane1 = min(lane0 + self.chunk, total)
-                p0 = int(np.searchsorted(offs, lane0, side="right")) - 1
-                p1 = int(np.searchsorted(offs, lane1, side="left"))
-                npair = p1 - p0
-                pcap = _cap(npair, 256)
-                psl = np.zeros(pcap, np.int32)
-                pbl = np.zeros(pcap, np.int32)
-                ofl = np.full(pcap, np.int64(1 << 40), np.int64)
-                psl[:npair] = ps[p0:p1]
-                pbl[:npair] = pb[p0:p1]
-                ofl[:npair] = offs[p0:p1] - lane0
-                ofl = np.clip(ofl, -(1 << 30), 1 << 30).astype(np.int32)
-                sc, sl, ev, cc = _eval_general_chunk(
-                    jnp.asarray(psl), jnp.asarray(pbl), jnp.asarray(ofl),
-                    jnp.int32(npair), jnp.int32(lane1 - lane0),
-                    self.dg.adj, self.memo_cost, self.memo_rows, *self._targs,
-                    nmax=self.nmax, chunk=self.chunk, pcap=pcap,
-                    typed=self.typed)
-                sc, sl, ev, cc = fetch((sc, sl, ev, cc))
-                self.counters.evaluated += int(ev)
-                self.counters.ccp += int(cc)
-                scn = sc[:npair]
+            for (p0, p1), out in fetch_in_waves(
+                    lambda lane0: self._launch_general(ps, pb, offs, total,
+                                                       lane0),
+                    range(0, total, self.chunk)):
+                pcap = (len(out) - 2) // 2
+                self.counters.evaluated += int(out[-2])
+                self.counters.ccp += int(out[-1])
+                scn = out[:p1 - p0].view(np.float32)
                 fin = np.isfinite(scn)
                 k_all.append(pk[p0:p1][fin])
                 c_all.append(scn[fin])
-                l_all.append(sl[:npair][fin])
+                l_all.append(out[pcap:pcap + p1 - p0][fin])
             if k_all:
                 _merge_scattered(best_cost, best_left, np.concatenate(k_all),
                                  np.concatenate(c_all), np.concatenate(l_all))

@@ -13,14 +13,23 @@ from . import cost as cm
 
 @dataclasses.dataclass
 class Counters:
-    """Paper §2.1: EvaluatedCounter vs CCP-Counter (symmetric pairs included)."""
+    """Paper §2.1: EvaluatedCounter vs CCP-Counter (symmetric pairs included),
+    and the enumeration's own tallies: the lane slots the connected-set
+    filter launched (chunk padding included), the connected sets of two or
+    more relations it found, and the bytes of the dense memo."""
 
     evaluated: int = 0
     ccp: int = 0
+    filter_lanes: int = 0
+    filter_sets: int = 0
+    memo_bytes: int = 0
 
     def __iadd__(self, other: "Counters"):
         self.evaluated += other.evaluated
         self.ccp += other.ccp
+        self.filter_lanes += other.filter_lanes
+        self.filter_sets += other.filter_sets
+        self.memo_bytes = max(self.memo_bytes, other.memo_bytes)
         return self
 
 

@@ -36,8 +36,10 @@ and the double-buffered stream loop interleaves them across flights:
                finalize done) and finalize_s (the overlappable share)
 
 Solo queries (bucket rejects: n > NMAX cap, exotic statics) fall back to
-per-query ``engine.optimize`` after all flights land; deferred duplicates
-resolve last, off the canonical results (``resolve_deferred``).  With a
+per-query ``engine.optimize`` after all flights land, each under a
+``service.solo`` span and recorded as a solo telemetry record
+(``StreamReport.solo_telemetry``); deferred duplicates resolve last, off
+the canonical results (``resolve_deferred``).  With a
 mesh, oversized-but-exact-eligible queries (``nmax_bucket(n) > NMAX_BATCH``,
 ``n <= lattice.NMAX_LATTICE``) are instead admitted as single-query
 **lattice flights** (``lattice.LatticeShardedEngine``: the one query's lane
@@ -58,6 +60,7 @@ import time
 
 import numpy as np
 
+from . import bitset as bs
 from . import faults
 from . import telemetry as _telemetry
 from .telemetry import span
@@ -96,6 +99,8 @@ class StreamReport:
     cache_hits: int = 0
     solo: int = 0                  # queries that fell back to per-query runs
     lattice: int = 0               # finalized intra-query lattice flights
+    # one telemetry.FlightTelemetry record (``solo`` set) per solo run
+    solo_telemetry: list = dataclasses.field(default_factory=list)
 
     def latency_percentiles(self, ps=(50, 95, 99)) -> dict[int, float]:
         if not self.latency_s:
@@ -104,8 +109,10 @@ class StreamReport:
         return {p: float(np.percentile(xs, p)) for p in ps}
 
     def telemetry_summary(self) -> dict:
-        """Stream-wide roll-up of the per-flight telemetry records."""
-        return _telemetry.aggregate(fl.telemetry for fl in self.flights)
+        """Stream-wide roll-up of the per-flight and solo telemetry
+        records."""
+        return _telemetry.aggregate(
+            [fl.telemetry for fl in self.flights] + self.solo_telemetry)
 
 
 class StreamOptimizer:
@@ -299,13 +306,19 @@ class StreamOptimizer:
                 self._finalize(graphs, *prev, t_stream, results, report)
 
         for qi in solo:
-            if self.config.deadline_s is None:
-                r = _eng.optimize(graphs[qi], self.algorithm,
-                                  chunk=self.chunk)
-            else:
-                r = _eng.optimize(graphs[qi], config=OptimizerConfig(
-                    algorithm=self.algorithm, chunk=self.chunk,
-                    deadline_s=self._left()))
+            n = graphs[qi].n
+            nmax = bs.nmax_bucket(n)  # the bucket admission refused it at
+            t_solo = time.perf_counter()
+            with span("service.solo", n=n, nmax=nmax):
+                if self.config.deadline_s is None:
+                    r = _eng.optimize(graphs[qi], self.algorithm,
+                                      chunk=self.chunk)
+                else:
+                    r = _eng.optimize(graphs[qi], config=OptimizerConfig(
+                        algorithm=self.algorithm, chunk=self.chunk,
+                        deadline_s=self._left()))
+            report.solo_telemetry.append(_telemetry.capture_solo(
+                r, nmax=nmax, wall_s=time.perf_counter() - t_solo))
             results[qi] = r
             report.latency_s[qi] = time.perf_counter() - t_stream
             if self.cache is not None and "degraded" not in r.info:

@@ -12,6 +12,12 @@ per chunk dispatch), so capturing it cannot perturb costs, plans, or
 lane counters — which is what lets ``core.service`` attach telemetry to
 every ``FlightReport`` unconditionally, policy learning on or off.
 
+A query the batched engines cannot take runs alone on the solo
+``engine.ExactEngine``; :func:`capture_solo` folds each such run into a
+record of its own, ``queries=1`` and ``solo`` set, from the counters on
+its result: the lane slots the connected-set filter launched, the sets it
+found and the bytes of the dense memo.
+
 Records feed :class:`repro.core.policy.PolicyTable`, which EMA-learns
 per-(NMAX bucket, lane space) execution profiles, and the daemon's
 STATS reply, which aggregates them across requests.  See
@@ -47,6 +53,26 @@ def fetch(tree):
         return jax.device_get(tree)
 
 
+# launches dispatched ahead of one fetch of all their outputs: a blocking
+# round trip costs a v5e's host several ms, as much as a chunk's work, and
+# this many chunks' outputs stay small beside the memo
+FETCH_WAVE = 256
+
+
+def fetch_in_waves(launch, items):
+    """``launch(item)`` gives ``(meta, device outputs)``; dispatches them
+    ``FETCH_WAVE`` at a time and yields ``(meta, fetched outputs)`` in
+    order, one ``fetch`` a wave."""
+    wave = []
+    for item in items:
+        wave.append(launch(item))
+        if len(wave) == FETCH_WAVE:
+            yield from zip([m for m, _ in wave], fetch([o for _, o in wave]))
+            wave = []
+    if wave:
+        yield from zip([m for m, _ in wave], fetch([o for _, o in wave]))
+
+
 @dataclasses.dataclass
 class FlightTelemetry:
     """One flight's execution profile.  All fields are plain host scalars."""
@@ -60,6 +86,10 @@ class FlightTelemetry:
     chunks: int = 0           # chunk dispatches across all levels/stages
     blocks_sets: int = 0      # sets given to phase A's block finding
     blocks_slots: int = 0     # set slots its launches held (>= blocks_sets)
+    solo: bool = False        # one query run alone on the solo engine
+    filter_lanes: int = 0     # lane slots the set filter launched (padded)
+    filter_sets: int = 0      # connected sets (>= 2 relations) it found
+    memo_bytes: int = 0       # bytes of the dense memo tables
     retraces: int = 0         # executable-cache retraces charged to the flight
     result_cost: float = 0.0  # sum of final plan costs (f32 exact-min costs)
     wall_s: float = 0.0       # run_levels wall (service: stamped in _finalize)
@@ -111,13 +141,30 @@ def capture(eng, results, *, nmax: int, queries: int, lattice: bool = False,
     )
 
 
+def capture_solo(result, *, nmax: int, wall_s: float = 0.0
+                 ) -> FlightTelemetry:
+    """A :class:`FlightTelemetry` record of one solo run, from the
+    ``Counters`` on its ``OptimizeResult``."""
+    c = result.counters
+    return FlightTelemetry(
+        nmax=int(nmax), space=str(result.algorithm), queries=1, solo=True,
+        evaluated_lanes=int(c.evaluated), ccp_lanes=int(c.ccp),
+        filter_lanes=int(c.filter_lanes), filter_sets=int(c.filter_sets),
+        memo_bytes=int(c.memo_bytes), result_cost=float(result.cost),
+        wall_s=float(wall_s))
+
+
 def aggregate(records) -> dict:
-    """Fold an iterable of flight telemetry records into one summary dict.
+    """Fold an iterable of telemetry records into one summary dict: the
+    flights' records into the flight keys, the solo runs' into the
+    ``solo_*`` keys (``solo_memo_bytes`` the largest memo, the rest sums).
 
     ``None`` entries are skipped so callers can pass
     ``[fl.telemetry for fl in report.flights]`` without filtering.
     """
     recs = [r for r in records if r is not None]
+    solo = [r for r in recs if r.solo]
+    recs = [r for r in recs if not r.solo]
     out = {
         "flights": len(recs),
         "queries": sum(r.queries for r in recs),
@@ -129,7 +176,23 @@ def aggregate(records) -> dict:
         "retraces": sum(r.retraces for r in recs),
         "result_cost": float(sum(r.result_cost for r in recs)),
         "wall_s": float(sum(r.wall_s for r in recs)),
+        "solo_queries": len(solo),
+        "solo_filter_lanes": sum(r.filter_lanes for r in solo),
+        "solo_filter_sets": sum(r.filter_sets for r in solo),
+        "solo_memo_bytes": max((r.memo_bytes for r in solo), default=0),
     }
     slots = sum(r.chunks * r.chunk for r in recs)
     out["occupancy"] = (out["evaluated_lanes"] / slots) if slots else 0.0
     return out
+
+
+# summary keys that roll up as the largest value seen, not as a sum
+LARGEST = frozenset({"solo_memo_bytes"})
+
+
+def roll_up(total: dict, summary: dict) -> None:
+    """Add one :func:`aggregate` summary into a running ``total`` (its keys
+    only): sums, and the largest value for the :data:`LARGEST` keys."""
+    for k in total:
+        v = int(summary.get(k, 0))
+        total[k] = max(total[k], v) if k in LARGEST else total[k] + v

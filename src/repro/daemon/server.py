@@ -50,6 +50,7 @@ from collections import deque
 
 from . import protocol as proto
 from ..core import faults
+from ..core import telemetry as _telemetry
 from ..core.telemetry import span
 
 
@@ -150,11 +151,14 @@ class OptimizerDaemon:
         self._queue_waits: deque[float] = deque(maxlen=history)
         self._request_walls: deque[float] = deque(maxlen=history)
         self._flight_walls: deque[float] = deque(maxlen=history)
-        # flight-telemetry roll-up (telemetry.aggregate shape, summed
-        # across every finalized flight of every request)
+        # telemetry roll-up (telemetry.aggregate shape, folded across every
+        # finalized flight and solo run of every request by
+        # telemetry.roll_up)
         self._telemetry = {"flights": 0, "queries": 0, "evaluated_lanes": 0,
                            "ccp_lanes": 0, "chunks": 0, "retraces": 0,
-                           "blocks_sets": 0, "blocks_slots": 0}
+                           "blocks_sets": 0, "blocks_slots": 0,
+                           "solo_queries": 0, "solo_filter_lanes": 0,
+                           "solo_filter_sets": 0, "solo_memo_bytes": 0}
 
     # ------------------------------------------------------------ lifecycle -
     def start(self) -> None:
@@ -455,8 +459,7 @@ class OptimizerDaemon:
             self._flights += len(report.flights)
             self._request_walls.append(time.perf_counter() - job.admitted)
             self._flight_walls.extend(f.wall_s for f in report.flights)
-            for k in self._telemetry:
-                self._telemetry[k] += int(tele.get(k, 0))
+            _telemetry.roll_up(self._telemetry, tele)
             tt = self._tenant_totals.setdefault(
                 job.tenant, {"requests": 0, "queries": 0, "shed": 0})
             tt["requests"] += 1

@@ -58,3 +58,49 @@ def test_stream_summary_counts_every_set_phase_a_saw(g, cyclic):
         assert agg["blocks_slots"] % 256 == 0
     else:
         assert agg["blocks_sets"] == agg["blocks_slots"] == 0
+
+
+def test_aggregate_keeps_solo_runs_out_of_the_flight_keys():
+    flight = telemetry.FlightTelemetry(nmax=16, space="s", queries=3,
+                                       evaluated_lanes=50)
+    solos = [telemetry.FlightTelemetry(nmax=nm, space="mpdp_general",
+                                       queries=1, solo=True,
+                                       evaluated_lanes=7, filter_lanes=fl,
+                                       filter_sets=fs, memo_bytes=16 << nm)
+             for nm, fl, fs in ((24, 65536, 900), (25, 32768, 40))]
+    agg = telemetry.aggregate([flight, None] + solos)
+    assert (agg["flights"], agg["queries"], agg["evaluated_lanes"]) == \
+        (1, 3, 50)
+    assert (agg["solo_queries"], agg["solo_filter_lanes"],
+            agg["solo_filter_sets"], agg["solo_memo_bytes"]) == \
+        (2, 98304, 940, 16 << 25)
+
+
+def test_stream_records_each_solo_run():
+    """A query past the batched memo runs on the solo engine; the stream's
+    summary counts it in the solo keys, from the counters on its result."""
+    g = gen.musicbrainz_query(17, 1)
+    (r,), rep = optimize_stream([g])
+    (t,) = rep.solo_telemetry
+    assert rep.solo == 1 and not rep.flights
+    assert (t.solo, t.queries, t.nmax, t.space) == (True, 1, 24, r.algorithm)
+    assert (t.filter_lanes, t.filter_sets, t.memo_bytes) == \
+        (r.counters.filter_lanes, r.counters.filter_sets, 16 << 24)
+    agg = rep.telemetry_summary()
+    assert agg["flights"] == agg["queries"] == 0
+    assert agg["solo_queries"] == 1
+    assert 0 < agg["solo_filter_sets"] <= agg["solo_filter_lanes"]
+
+
+def test_roll_up_sums_summaries_and_keeps_the_largest_memo():
+    """The daemon's STATS roll-up: each request's summary adds in, except
+    the keys that keep the largest value seen."""
+    total = {"queries": 0, "solo_queries": 0, "solo_memo_bytes": 0}
+    for nm in (25, 24):
+        rec = telemetry.FlightTelemetry(nmax=nm, space="mpdp_general",
+                                        queries=1, solo=True,
+                                        memo_bytes=16 << nm)
+        telemetry.roll_up(total, telemetry.aggregate([rec]))
+    assert total == {"queries": 0, "solo_queries": 2,
+                     "solo_memo_bytes": 16 << 25}
+    assert telemetry.LARGEST == {"solo_memo_bytes"}
